@@ -27,8 +27,12 @@ from repro.core import stage1 as stage1_lib
 from repro.core.lstm import SELECTORS
 
 
+@jax.tree_util.register_dataclass
 @dataclasses.dataclass
 class CluSDIndex:
+    """A pytree of index arrays: serving programs take it as a jit
+    argument (placed on the device once), so no index array is ever
+    compiled into a program as a constant. Unset fields are None."""
     centroids: Any          # (N, dim)
     cluster_docs: Any       # (N, cap) int32, -1 pad
     doc_cluster: Any        # (D,) int32

@@ -9,6 +9,7 @@
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -17,6 +18,9 @@ import numpy as np
 from repro.core import kmeans as km
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["codebooks", "codes", "rotation"],
+                   meta_fields=["nsub"])
 @dataclasses.dataclass
 class PQ:
     codebooks: jnp.ndarray   # (nsub, 256, dsub)
@@ -113,13 +117,16 @@ def decode_code_blocks(codebooks, codes, rotation=None):
 
 
 def adc_tables(pq: PQ, q):
-    """q: (B, dim) -> LUT (B, nsub, 256)."""
+    """q: (B, dim) -> LUT (B, nsub, 256), in float32 (HIGHEST precision:
+    on TPU the default would round the operands to bf16, and the ADC
+    kernels build their LUT in float32)."""
+    hi = jax.lax.Precision.HIGHEST
     if pq.rotation is not None:
-        q = q @ pq.rotation
+        q = jnp.dot(q, pq.rotation, precision=hi)
     B = q.shape[0]
     dsub = pq.codebooks.shape[-1]
     qs = q.reshape(B, pq.nsub, dsub)
-    return jnp.einsum("bsd,skd->bsk", qs, pq.codebooks)
+    return jnp.einsum("bsd,skd->bsk", qs, pq.codebooks, precision=hi)
 
 
 def adc_score(pq: PQ, lut, doc_ids):

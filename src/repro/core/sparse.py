@@ -8,42 +8,52 @@ L(q) . L(d) = sum over shared terms of qw * dw.
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["postings_docs", "postings_weights"],
+                   meta_fields=["n_docs"])
 @dataclasses.dataclass
 class SparseIndex:
+    """A pytree: the posting arrays are leaves (jit arguments, never
+    compiled-in constants); `n_docs` is static aux data."""
     postings_docs: jnp.ndarray     # (V, P) int32, -1 padded, impact-ordered
     postings_weights: jnp.ndarray  # (V, P) f32
     n_docs: int
 
     @staticmethod
     def build(doc_terms, doc_weights, vocab, max_postings):
-        """doc_terms: (D, T) int32 term ids (-1 pad); doc_weights: (D, T) f32."""
+        """doc_terms: (D, T) int32 term ids (-1 pad); doc_weights: (D, T) f32.
+
+        Each term's postings are its (weight, doc) pairs with weight > 0 in
+        descending tuple order (weight desc, ties doc id desc), truncated
+        to `max_postings`."""
         doc_terms = np.asarray(doc_terms)
         doc_weights = np.asarray(doc_weights)
         D, T = doc_terms.shape
-        lists = [[] for _ in range(vocab)]
-        for d in range(D):
-            for t, w in zip(doc_terms[d], doc_weights[d]):
-                if t >= 0 and w > 0:
-                    lists[int(t)].append((float(w), d))
+        keep = (doc_terms >= 0) & (doc_weights > 0)
+        t = doc_terms[keep].astype(np.int64)
+        w = doc_weights[keep]
+        d = np.broadcast_to(np.arange(D, dtype=np.int64)[:, None],
+                            (D, T))[keep]
+        order = np.lexsort((-d, -w, t))     # term asc, weight desc, doc desc
+        t, w, d = t[order], w[order], d[order]
+        counts = np.bincount(t, minlength=vocab)
+        starts = np.cumsum(counts) - counts
+        rank = np.arange(len(t)) - starts[t]
+        fit = rank < max_postings
         pd = np.full((vocab, max_postings), -1, np.int32)
         pw = np.zeros((vocab, max_postings), np.float32)
-        truncated = 0
-        for t in range(vocab):
-            lst = sorted(lists[t], reverse=True)  # impact order
-            if len(lst) > max_postings:
-                truncated += len(lst) - max_postings
-            lst = lst[:max_postings]
-            for i, (w, d) in enumerate(lst):
-                pd[t, i] = d
-                pw[t, i] = w
+        pd[t[fit], rank[fit]] = d[fit]
+        pw[t[fit], rank[fit]] = w[fit]
         idx = SparseIndex(jnp.asarray(pd), jnp.asarray(pw), D)
-        idx.truncated_postings = truncated
+        idx.truncated_postings = int(
+            np.maximum(counts - max_postings, 0).sum())
         return idx
 
 

@@ -42,14 +42,19 @@ from repro.obs import NOOP_TRACE
 # ---------------------------------------------------------------------------
 # compiled stage builders (shared by RetrievalEngine and ShardRouter)
 # ---------------------------------------------------------------------------
-# Each returns a fresh jitted fn closing over (cfg, index/codebooks) AS
-# PASSED — callers key them per request bucket and drop them when the
-# closed-over state moves (index reloads; selector publishes for stage2).
+# Each returns a fresh jitted fn that closes over `cfg` (static Python
+# values only) and takes every index array as a jit ARGUMENT: the caller
+# places the index pytree (core.clusd.CluSDIndex, the device stores, the
+# PQ codebooks) on the device once and passes it on every call, so no
+# program carries an index array as a compiled-in constant. Arguments a
+# stage does not read are pruned from its executable by jit. Callers key
+# the fns per request bucket and drop them when `cfg` moves (selector
+# publishes for stage2 and the fused tails).
 
-def build_stage1_fn(cfg, index):
+def build_stage1_fn(cfg):
     """Sparse retrieval + Stage-I candidate generation.
-    fn(qd, qt, qw) -> (sparse_ids, sparse_scores, cand, feats)."""
-    def run(qd, qt, qw):
+    fn(index, qd, qt, qw) -> (sparse_ids, sparse_scores, cand, feats)."""
+    def run(index, qd, qt, qw):
         sid, ss = sparse_lib.sparse_retrieve_topk(
             index.sparse_index, qt, qw, cfg.k_sparse)
         s1 = clusd_lib.stage1_candidates(cfg, index, qd, sid, ss)
@@ -57,24 +62,32 @@ def build_stage1_fn(cfg, index):
     return jax.jit(run)
 
 
-def build_stage2_fn(cfg, index):
+def build_stage2_fn(cfg):
     """Stage-II LSTM cluster selection.
-    fn(cand, feats) -> (sel_ids, sel_mask, probs) — probs are the raw
-    per-candidate selector probabilities (explain telemetry compares them
-    against theta/budget; they are computed anyway, so returning them is
-    free)."""
-    def run(cand, feats):
+    fn(index, cand, feats) -> (sel_ids, sel_mask, probs) — probs are the
+    raw per-candidate selector probabilities (explain telemetry compares
+    them against theta/budget; they are computed anyway, so returning them
+    is free). Only `index.lstm_params` reaches the executable."""
+    def run(index, cand, feats):
         s2 = clusd_lib.stage2_select(cfg, index, cand, feats)
         return s2["sel_ids"], s2["sel_mask"], s2["probs"]
     return jax.jit(run)
 
 
-def build_lut_fn(codebooks, rotation):
+def build_lut_fn():
     """Per-query ADC LUT build (OPQ rotation folded in).
-    fn(qd) -> (B, nsub, 256) float32."""
-    cb = jnp.asarray(codebooks)
-    rot = None if rotation is None else jnp.asarray(rotation)
-    return jax.jit(lambda qd: adc_ops.adc_tables(qd, cb, rot))
+    fn(codebooks, rotation, qd) -> (B, nsub, 256) float32."""
+    return jax.jit(lambda codebooks, rotation, qd:
+                   adc_ops.adc_tables(qd, codebooks, rotation))
+
+
+def build_device_fn(cfg, *, k):
+    """The whole pipeline in one program for device stores (InMemoryStore,
+    PQStore). fn(index, store, qd, qt, qw) -> (ids, scores, n_selected)."""
+    def run(index, store, qd, qt, qw):
+        ids, scores, diag = retrieve(cfg, index, store, qd, qt, qw, k=k)
+        return ids, scores, diag["n_selected"]
+    return jax.jit(run)
 
 
 # ---------------------------------------------------------------------------
@@ -152,7 +165,7 @@ def dedup_selected(sel_ids, sel_mask):
     return uniq, pos.astype(np.int32)
 
 
-def build_fused_scorer(cfg, index, store, *, k, mode):
+def build_fused_scorer(cfg, n_docs, *, k, mode):
     """Compile score -> mask -> fuse -> top-k into one jitted pass.
 
     mode "adc":  blocks are (U, cap, nsub) uint8 PQ codes and q_or_lut is
@@ -160,15 +173,13 @@ def build_fused_scorer(cfg, index, store, *, k, mode):
                  once per batch — the OPQ rotation is already folded in).
     mode "dot":  blocks are (U, cap, dim) float and q_or_lut is (B, dim).
 
-    The returned fn(q_or_lut, sid, ss, sel_ids, sel_mask, blocks, pos)
-    -> (ids, scores) closes over cfg/cluster_docs (including the fusion
-    method/rrf_k), so the engine must drop it on index reloads (and on
-    selector reloads: cfg is re-read)."""
-    n_docs, alpha = index.n_docs, cfg.alpha
-    method, rrf_k = cfg.fusion, cfg.rrf_k
-    cluster_docs = index.cluster_docs
+    The returned fn(cluster_docs, q_or_lut, sid, ss, sel_ids, sel_mask,
+    blocks, pos) -> (ids, scores) closes over cfg (including the fusion
+    method/rrf_k) and the corpus size, so the engine must drop it on index
+    and selector reloads (cfg is re-read)."""
+    alpha, method, rrf_k = cfg.alpha, cfg.fusion, cfg.rrf_k
 
-    def run(q_or_lut, sid, ss, sel_ids, sel_mask, blocks, pos):
+    def run(cluster_docs, q_or_lut, sid, ss, sel_ids, sel_mask, blocks, pos):
         docs = jnp.take(cluster_docs, sel_ids, axis=0)         # (B, S, cap)
         B, S, cap = docs.shape
         valid = (docs >= 0) & sel_mask[:, :, None]

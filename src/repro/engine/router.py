@@ -71,6 +71,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.engine import pipeline as pipe_lib
+from repro.engine import stores as stores_lib
 from repro.engine.cache import BlockCache
 from repro.engine.server import (ServeStats, _pad_rows, bucket_size,
                                  build_explain_records)
@@ -470,7 +471,9 @@ class ShardRouter:
                              f"got {fusion!r}")
         self._fusion_override = fusion
         self.cfg = self._apply_cfg_overrides(cfg)
-        self.index = index
+        # Stage I/II + the LUT build run here: their index arrays go to the
+        # device once and are passed to the compiled stages as arguments
+        self.index = jax.device_put(index)
         self.reader = reader
         self.hosts: List[Any] = list(hosts)
         self.placement = placement
@@ -480,6 +483,8 @@ class ShardRouter:
         self.max_batch = max(1, max_batch)
         self.k = k or cfg.k_final
         self.use_adc = bool(reader.is_pq)
+        self._codebooks = stores_lib.place_codebooks(reader) \
+            if self.use_adc else None
         self.host_timeout = float(host_timeout)
         self.max_retries = int(max_retries)
         self.backoff_ms = float(backoff_ms)
@@ -568,17 +573,14 @@ class ShardRouter:
 
     def _stage1_fn(self, bucket):
         return self._fn("stage1", bucket,
-                        lambda: pipe_lib.build_stage1_fn(self.cfg, self.index))
+                        lambda: pipe_lib.build_stage1_fn(self.cfg))
 
     def _stage2_fn(self, bucket):
         return self._fn("stage2", bucket,
-                        lambda: pipe_lib.build_stage2_fn(self.cfg, self.index))
+                        lambda: pipe_lib.build_stage2_fn(self.cfg))
 
     def _lut_fn(self, bucket):
-        def build():
-            return pipe_lib.build_lut_fn(self.reader._pq_array("codebooks"),
-                                         self.reader._pq_array("rotation"))
-        return self._fn("lut", bucket, build)
+        return self._fn("lut", bucket, pipe_lib.build_lut_fn)
 
     def _fuse_fn(self, bucket, kd):
         """Fuse the merged dense candidate list with the sparse side — the
@@ -674,14 +676,16 @@ class ShardRouter:
             qw = jnp.asarray(_pad_rows(q_weights, pad))
         t0 = time.perf_counter()
         with tr.span("stage1"):
-            sid, ss, cand, feats = self._stage1_fn(bucket)(qd, qt, qw)
+            sid, ss, cand, feats = self._stage1_fn(bucket)(self.index,
+                                                           qd, qt, qw)
         q_or_lut = qd
         if self.use_adc:
             with tr.span("lut_build"):
-                q_or_lut = self._lut_fn(bucket)(qd)
+                q_or_lut = self._lut_fn(bucket)(*self._codebooks, qd)
                 q_or_lut.block_until_ready()
         with tr.span("stage2_select"):
-            sel_ids, sel_mask, probs = self._stage2_fn(bucket)(cand, feats)
+            sel_ids, sel_mask, probs = self._stage2_fn(bucket)(self.index,
+                                                               cand, feats)
             sel_np = np.asarray(sel_ids)
             mask_np = np.asarray(sel_mask)
         mode = "adc" if self.use_adc else "dot"
@@ -870,9 +874,13 @@ class ShardRouter:
             for host in self.hosts:        # roll host-by-host
                 with tr.span("prepare_host", host=host.host_id):
                     host.prepare_generation(self.reader, new_gen).result()
+            index = jax.device_put(index)
+            codebooks = stores_lib.place_codebooks(self.reader) \
+                if self.reader.is_pq else None
             with self._swap_lock:
                 self.cfg, self.index = cfg, index
                 self.use_adc = bool(self.reader.is_pq)
+                self._codebooks = codebooks
                 self._shard_his = self._read_shard_his(self.reader)
                 self._fns.clear()
                 self._generation = new_gen
@@ -898,7 +906,7 @@ class ShardRouter:
         if self.reader.generation == self._generation:
             return self._generation
         cfg = self._apply_cfg_overrides(self.reader.config())
-        params = self.reader.lstm_params()
+        params = jax.device_put(self.reader.lstm_params())
         # a selector publish is still a generation hop: hosts key their
         # stores by generation, so they adopt it too (content-identical —
         # the prepare is mmap-open only)
@@ -909,7 +917,7 @@ class ShardRouter:
         with self._swap_lock:
             old_cfg = self.cfg
             self.cfg = cfg
-            self.index.lstm_params = params
+            self.index = dataclasses.replace(self.index, lstm_params=params)
             stale = {"stage2", "fuse"}
             if RetrievalEngine._stage1_cfg(old_cfg) != \
                     RetrievalEngine._stage1_cfg(cfg):

@@ -293,9 +293,12 @@ class RetrievalEngine:
         self._fusion_override = fusion
         cfg = self._apply_cfg_overrides(cfg)
         self.cfg = cfg
-        self.index = index
-        self.store = store if store is not None \
-            else stores_lib.store_for_index(index)
+        # index arrays (and a device store's) go to the device ONCE; every
+        # compiled stage takes them as jit arguments (engine/pipeline.py)
+        self.index = jax.device_put(index)
+        self.store = self._place_store(
+            store if store is not None
+            else stores_lib.store_for_index(self.index))
         self.is_host = bool(getattr(self.store, "is_host", False))
         self.max_batch = max(1, max_batch)
         self.k = k or cfg.k_final
@@ -305,6 +308,8 @@ class RetrievalEngine:
         # True demands a code-backed store; False forces decode-then-score.
         self._explicit_use_adc = use_adc
         self.use_adc = self._resolve_use_adc(self.store)
+        self._codebooks = stores_lib.place_codebooks(self.store) \
+            if self.use_adc else None
         # observability (repro.obs): the registry backs stats()/ServeStats;
         # the tracer emits per-batch stage spans when trace_sample_rate > 0
         # (0 by default: the disabled path hands out a shared no-op trace).
@@ -361,6 +366,14 @@ class RetrievalEngine:
             raise ValueError("use_adc=True needs a code-backed store "
                              "(is_coded); this store serves float blocks")
         return bool(self._explicit_use_adc) and self.is_host
+
+    @staticmethod
+    def _place_store(store):
+        """Device stores are pytrees of arrays: place them once. Host
+        stores do their own block I/O and stay as they are."""
+        if getattr(store, "is_host", False):
+            return store
+        return jax.device_put(store)
 
     def _make_cache(self, store):
         """Byte-budgeted cache sized in float32-block equivalents when the
@@ -440,6 +453,7 @@ class RetrievalEngine:
             reader.refresh(verify=verify)
             cfg, index = reader.load_index()
             cfg = self._apply_cfg_overrides(cfg)
+            index = jax.device_put(index)
             store = reader.open_store(cluster_docs=index.cluster_docs)
             # quiesce prefetch: drop queued candidate ids and wait out any
             # fetch against the old store before the cache is cleared
@@ -452,6 +466,8 @@ class RetrievalEngine:
                 self.cfg, self.index, self.store = cfg, index, store
                 self.reader = reader
                 self.use_adc = self._resolve_use_adc(store)
+                self._codebooks = stores_lib.place_codebooks(store) \
+                    if self.use_adc else None
                 self._refresh_prefetch_depth(cfg)
                 self._fns.clear()           # bucket shapes/geometry changed
                 self._carry_store_counters(old_store, store)
@@ -526,21 +542,22 @@ class RetrievalEngine:
         tr = self.tracer.trace("reload_selector")
         with tr.span("reload"):
             cfg = self._apply_cfg_overrides(reader.config())
-            params = reader.lstm_params()
+            params = jax.device_put(reader.lstm_params())
             with self._swap_lock:
                 old_cfg = self.cfg
                 self.cfg = cfg
-                self.index.lstm_params = params
+                self.index = dataclasses.replace(self.index,
+                                                 lstm_params=params)
                 self.reader = reader
                 # the calibrated budget may exceed the old one: keep the
                 # prefetch window covering the selection
                 self._refresh_prefetch_depth(cfg)
                 # only selector-dependent compilations are stale: stage2
-                # closes over (params, theta, max_selected); the fused
+                # closes over (theta, max_selected); the fused
                 # device path and the fused host tails close over the
                 # whole (re-read) config. Stage-I buckets, the LUT builder
                 # (codebooks only), and the block cache survive — the
-                # corpus didn't move.
+                # corpus didn't move. (The new params are an argument.)
                 stale = {"stage2", "device", "adc", "dot"}
                 if self._stage1_cfg(old_cfg) != self._stage1_cfg(cfg):
                     # a publish may also retune candidate generation
@@ -621,36 +638,28 @@ class RetrievalEngine:
         return fn
 
     def _device_fn(self, bucket):
-        def build():
-            def run(qd, qt, qw):
-                ids, scores, diag = pipe_lib.retrieve(
-                    self.cfg, self.index, self.store, qd, qt, qw, k=self.k)
-                return ids, scores, diag["n_selected"]
-            return jax.jit(run)
-        return self._fn("device", bucket, build)
+        return self._fn("device", bucket,
+                        lambda: pipe_lib.build_device_fn(self.cfg, k=self.k))
 
     def _stage1_fn(self, bucket):
         return self._fn("stage1", bucket,
-                        lambda: pipe_lib.build_stage1_fn(self.cfg, self.index))
+                        lambda: pipe_lib.build_stage1_fn(self.cfg))
 
     def _stage2_fn(self, bucket):
         return self._fn("stage2", bucket,
-                        lambda: pipe_lib.build_stage2_fn(self.cfg, self.index))
+                        lambda: pipe_lib.build_stage2_fn(self.cfg))
 
     def _lut_fn(self, bucket):
         """Per-query ADC LUT build (rotation folded in). Keyed per bucket
-        only — survives selector reloads (closes over codebooks alone)."""
-        return self._fn("lut", bucket,
-                        lambda: pipe_lib.build_lut_fn(self.store.codebooks,
-                                                      self.store.rotation))
+        only — survives selector reloads (reads the codebooks alone)."""
+        return self._fn("lut", bucket, pipe_lib.build_lut_fn)
 
     def _fused_fn(self, kind, bucket, ubucket):
         """One compiled score->fuse->top-k tail per (mode, batch bucket,
         unique-block bucket)."""
         def build():
-            return pipe_lib.build_fused_scorer(self.cfg, self.index,
-                                               self.store, k=self.k,
-                                               mode=kind)
+            return pipe_lib.build_fused_scorer(self.cfg, self.index.n_docs,
+                                               k=self.k, mode=kind)
         return self._fn(kind, (bucket, ubucket), build)
 
     # -- serving ------------------------------------------------------------
@@ -697,7 +706,8 @@ class RetrievalEngine:
                 ids.block_until_ready()
             else:
                 with tr.span("device_pipeline"):
-                    ids, scores, _ = self._device_fn(bucket)(qd, qt, qw)
+                    ids, scores, _ = self._device_fn(bucket)(
+                        self.index, self.store, qd, qt, qw)
                     ids.block_until_ready()
             ms = (time.perf_counter() - t0) * 1e3
             # a batch "compiled" if ANY stage built a new jitted fn for it
@@ -718,7 +728,8 @@ class RetrievalEngine:
     def _serve_host(self, bucket, qd, qt, qw, tr=NOOP_TRACE, n=None):
         n = bucket if n is None else n
         with tr.span("stage1"):
-            sid, ss, cand, feats = self._stage1_fn(bucket)(qd, qt, qw)
+            sid, ss, cand, feats = self._stage1_fn(bucket)(self.index,
+                                                           qd, qt, qw)
             cand_np = np.asarray(cand)      # device sync for Stage I
             # overlap: start pulling candidate blocks while Stage II runs
             # (the enqueue itself is host work, charged to this span)
@@ -729,12 +740,13 @@ class RetrievalEngine:
             # prefetcher is pulling candidate code blocks
             with tr.span("lut_build"):
                 t0 = time.perf_counter()
-                lut = self._lut_fn(bucket)(qd)
+                lut = self._lut_fn(bucket)(*self._codebooks, qd)
                 lut.block_until_ready()
                 if not self._built_fn:   # steady-state only (no compile skew)
                     self._lut_build_ms.inc((time.perf_counter() - t0) * 1e3)
         with tr.span("stage2_select"):
-            sel_ids, sel_mask, probs = self._stage2_fn(bucket)(cand, feats)
+            sel_ids, sel_mask, probs = self._stage2_fn(bucket)(self.index,
+                                                               cand, feats)
             sel_np = np.asarray(sel_ids)    # device sync for Stage II
             mask_np = np.asarray(sel_mask)
         with tr.span("fuse"):               # host glue: dedup + positions
@@ -762,7 +774,8 @@ class RetrievalEngine:
             kind = "adc" if self.use_adc else "dot"
             fn = self._fused_fn(kind, bucket, ub)
             t0 = time.perf_counter()
-            ids, scores = fn(lut if self.use_adc else qd, sid, ss,
+            ids, scores = fn(self.index.cluster_docs,
+                             lut if self.use_adc else qd, sid, ss,
                              sel_ids, sel_mask, jnp.asarray(blocks),
                              jnp.asarray(pos))
             ids.block_until_ready()

@@ -38,6 +38,7 @@ thread safety — is documented in engine/README.md.
 
 from typing import Protocol, runtime_checkable
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -55,8 +56,10 @@ class ClusterStore(Protocol):
         ...
 
 
+@jax.tree_util.register_pytree_node_class
 class InMemoryStore:
-    """Device-resident embeddings; fetch is a jit-friendly gather."""
+    """Device-resident embeddings; fetch is a jit-friendly gather. A
+    pytree, so serving programs take its arrays as jit arguments."""
 
     is_host = False
     is_coded = False
@@ -64,6 +67,13 @@ class InMemoryStore:
     def __init__(self, embeddings, cluster_docs):
         self.embeddings = embeddings          # (D, dim)
         self.cluster_docs = cluster_docs      # (N, cap)
+
+    def tree_flatten(self):
+        return (self.embeddings, self.cluster_docs), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
 
     def fetch_blocks(self, cluster_ids):
         docs = jnp.take(self.cluster_docs, cluster_ids, axis=0)
@@ -78,13 +88,15 @@ class InMemoryStore:
         return jnp.einsum("bd,bkd->bk", q_dense, vecs)
 
 
+@jax.tree_util.register_pytree_node_class
 class PQStore:
     """Product-quantized embeddings; scoring via ADC lookup tables,
     block fetch via codebook reconstruction (identical scores up to fp).
 
     Code-backed (`is_coded`): `fetch_code_blocks` gathers raw per-cluster
     code blocks so the jit'd pipeline can score codes in-kernel, never
-    reconstructing float embeddings on the scoring path."""
+    reconstructing float embeddings on the scoring path. A pytree, like
+    InMemoryStore."""
 
     is_host = False
     is_coded = True
@@ -92,6 +104,13 @@ class PQStore:
     def __init__(self, pq, cluster_docs):
         self.pq = pq
         self.cluster_docs = cluster_docs
+
+    def tree_flatten(self):
+        return (self.pq, self.cluster_docs), None
+
+    @classmethod
+    def tree_unflatten(cls, _, children):
+        return cls(*children)
 
     @property
     def codebooks(self):
@@ -175,6 +194,13 @@ class DiskStore:
         with self._lock:
             self.stats.add(local.n_ops, local.bytes, local.wall_ms)
         return vecs, docs, docs >= 0
+
+
+def place_codebooks(source):
+    """The (codebooks, rotation) of a code-backed store or a PQ
+    IndexReader, placed on the device once: the ADC LUT-build programs of
+    the engine and the router take them as jit arguments."""
+    return jax.device_put((source.codebooks, source.rotation))
 
 
 def store_for_index(index):

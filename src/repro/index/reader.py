@@ -133,6 +133,16 @@ class IndexReader:
             return None
         return np.load(os.path.join(self.index_dir, rel))
 
+    @property
+    def codebooks(self):
+        """PQ codebooks (nsub, 256, dsub), or None without PQ artifacts."""
+        return self._pq_array("codebooks") if self.manifest["pq"] else None
+
+    @property
+    def rotation(self):
+        """The OPQ rotation (dim, dim), or None."""
+        return self._pq_array("rotation") if self.manifest["pq"] else None
+
     def _doc_codes(self):
         """Rebuild per-doc (D, nsub) codes from the v2 code shards (cheap:
         nsub bytes per doc) — lets device-side ADC (PQStore) serve a v2
@@ -242,8 +252,8 @@ class IndexReader:
             cluster_docs = self.array("cluster_docs")
         if self.is_pq:
             return ShardedPQStore(
-                paths, ranges, g["cap"], self._pq_array("codebooks"),
-                cluster_docs, rotation=self._pq_array("rotation"),
+                paths, ranges, g["cap"], self.codebooks,
+                cluster_docs, rotation=self.rotation,
                 out_dtype=np.dtype(g["block_dtype"]), tombstones=tomb,
                 stats=stats)
         return ShardedDiskStore(

@@ -32,7 +32,8 @@ def adc_tables(q, codebooks, rotation=None, *, use_kernel=None):
         return adc_tables_ref(q, codebooks, rotation)
     q = jnp.asarray(q, jnp.float32)
     if rotation is not None:
-        q = q @ jnp.asarray(rotation, jnp.float32)
+        q = jnp.dot(q, jnp.asarray(rotation, jnp.float32),
+                    precision=jax.lax.Precision.HIGHEST)
     return adc_tables_pallas(q, codebooks, interpret=interpret)
 
 

@@ -13,6 +13,7 @@ partial dots — identical math, reordered — so ADC scoring is
 rank-equivalent to decode-then-score and agrees to float rounding.
 """
 
+import jax
 import jax.numpy as jnp
 
 
@@ -24,13 +25,14 @@ def adc_tables_ref(q, codebooks, rotation=None):
     then never touched again — code scoring is rotation-free).
     Returns (B, nsub, K) float32.
     """
+    hi = jax.lax.Precision.HIGHEST      # float32 on TPU too, as the kernel
     q = jnp.asarray(q, jnp.float32)
     if rotation is not None:
-        q = q @ jnp.asarray(rotation, jnp.float32)
+        q = jnp.dot(q, jnp.asarray(rotation, jnp.float32), precision=hi)
     nsub, K, dsub = codebooks.shape
     qs = q.reshape(q.shape[0], nsub, dsub)
     return jnp.einsum("bsd,skd->bsk", qs,
-                      jnp.asarray(codebooks, jnp.float32))
+                      jnp.asarray(codebooks, jnp.float32), precision=hi)
 
 
 def adc_score_blocks_ref(lut, code_blocks, sel_ids):

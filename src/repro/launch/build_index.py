@@ -11,7 +11,14 @@ it later with `repro.launch.update_index` (incremental deltas).
   PYTHONPATH=src python -m repro.launch.build_index --out /tmp/idx_pq \
       --format-version 2 --pq-nsub 8 --memmap --chunk-docs 4096
 
+  # the paper's MS MARCO widths (dim 768, vocab 30,522, 4,096 postings per
+  # term, ...), cut only in corpus size and cluster count
+  PYTHONPATH=src python -m repro.launch.build_index --out /tmp/idx_full \
+      --variant full --docs 1048576 --clusters 1024 --format-version 2 \
+      --train-queries 0
+
 Key flags (the full list with defaults is below / `--help`):
+  --variant {smoke,full}  clusd-msmarco config; full = the paper's widths
   --format-version {1,2}  1 = float32 block shards; 2 = PQ code shards +
                           CSR postings (4-16x smaller; served via
                           decode-on-fetch ADC at exact-ADC numerics)
@@ -43,21 +50,29 @@ import jax
 import numpy as np
 
 from repro import index as index_lib
-from repro.configs import get_config
+from repro.common.compile_cache import place_compile_cache
+from repro.configs import clusd_msmarco, get_config
 from repro.core import train_lstm as tl
 from repro.data import synth_corpus, synth_queries
 
 
 def build_cfg(args):
-    k_sparse = max(32, min(512, args.docs // 4))
+    """--variant smoke: the small CPU-sized geometry, derived from --docs /
+    --dim / --clusters / --vocab. --variant full: the paper's MS MARCO
+    config (configs/clusd_msmarco.full()) at its published widths; only
+    --docs and --clusters cut it."""
+    if args.variant == "full":
+        return clusd_msmarco.from_cli(args)
+    a = clusd_msmarco.smoke_sizes(args)
+    k_sparse = max(32, min(512, a["docs"] // 4))
     bins = tuple(b for b in (10, 25, 50, 100, 200) if b < k_sparse) + (k_sparse,)
     return dataclasses.replace(
         get_config("clusd-msmarco", "smoke"),
-        n_docs=args.docs, dim=args.dim, n_clusters=args.clusters,
-        vocab=args.vocab, k_sparse=k_sparse, bins=bins,
-        n_candidates=min(32, args.clusters), max_selected=16,
-        k_final=min(256, args.docs),
-        train_queries=args.train_queries, epochs=args.epochs)
+        n_docs=a["docs"], dim=a["dim"], n_clusters=a["clusters"],
+        vocab=a["vocab"], k_sparse=k_sparse, bins=bins,
+        n_candidates=min(32, a["clusters"]), max_selected=16,
+        k_final=min(256, a["docs"]),
+        train_queries=a["train_queries"], epochs=a["epochs"])
 
 
 def main(argv=None):
@@ -68,15 +83,26 @@ def main(argv=None):
                     "pack, serialize + checksummed manifest).",
         epilog=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
     ap.add_argument("--out", required=True, help="index output directory")
-    ap.add_argument("--docs", type=int, default=20000)
-    ap.add_argument("--dim", type=int, default=64)
-    ap.add_argument("--clusters", type=int, default=256)
-    ap.add_argument("--vocab", type=int, default=2048)
+    ap.add_argument("--variant", default="smoke", choices=("smoke", "full"),
+                    help="clusd-msmarco config: smoke (small geometry from "
+                         "the flags below) or full (the paper's MS MARCO "
+                         "widths; --docs/--clusters are its only cuts)")
+    ap.add_argument("--docs", type=int, default=None,
+                    help="corpus size (default: 20000 smoke, the config's "
+                         "under full)")
+    ap.add_argument("--dim", type=int, default=None, help="smoke only (64)")
+    ap.add_argument("--clusters", type=int, default=None,
+                    help="cluster count (default: 256 smoke, the config's "
+                         "under full)")
+    ap.add_argument("--vocab", type=int, default=None,
+                    help="smoke only (2048)")
     ap.add_argument("--shards", type=int, default=4,
                     help="block shard files (and k-means embedding shards)")
-    ap.add_argument("--train-queries", type=int, default=512,
-                    help="0 skips LSTM selector training")
-    ap.add_argument("--epochs", type=int, default=40)
+    ap.add_argument("--train-queries", type=int, default=None,
+                    help="0 skips LSTM selector training (default: 512 "
+                         "smoke, the config's under full)")
+    ap.add_argument("--epochs", type=int, default=None,
+                    help="default: 40 smoke, the config's under full")
     ap.add_argument("--pq-nsub", type=int, default=0,
                     help="train PQ codebooks with this many subspaces "
                          "(v1: extra pq/ artifacts; v2: the code shards; "
@@ -93,6 +119,7 @@ def main(argv=None):
     ap.add_argument("--kmeans-iters", type=int, default=15)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     cfg = build_cfg(args)
     t0 = time.perf_counter()
@@ -115,12 +142,12 @@ def main(argv=None):
         corpus.doc_weights, shard_docs=shard_docs,
         kmeans_iters=args.kmeans_iters)
 
-    if args.train_queries > 0:
-        print(f"training LSTM selector on {args.train_queries} queries ...",
+    if cfg.train_queries > 0:
+        print(f"training LSTM selector on {cfg.train_queries} queries ...",
               flush=True)
         # labels need full dense retrieval — offline-only embedding use
         index.embeddings = corpus.embeddings
-        tq = synth_queries(args.seed + 1, corpus, args.train_queries)
+        tq = synth_queries(args.seed + 1, corpus, cfg.train_queries)
         _, feats, labels = tl.make_labels(cfg, index, tq.q_dense, tq.q_terms,
                                           tq.q_weights)
         index.lstm_params, hist = tl.train_selector(

@@ -23,6 +23,7 @@ import jax
 
 from repro.analysis.hlo import collective_bytes, hlo_cost
 from repro.analysis.roofline import model_flops, roofline_terms
+from repro.common.compile_cache import place_compile_cache
 from repro.configs import cells, get_config
 from repro.configs.shapes import shapes_for
 from repro.launch.mesh import make_production_mesh
@@ -111,6 +112,7 @@ def main():
     ap.add_argument("--set", action="append", default=[],
                     help="arch-config overrides key=value (perf variants)")
     args = ap.parse_args()
+    place_compile_cache()
 
     extra_rules = {}
     for kv in args.rules.split(","):

@@ -24,8 +24,11 @@ generation; deleted docs are tombstone-masked at fetch.
 
 --check-parity replays the queries through the in-memory pipeline and
 exits non-zero on mismatch: exact top-k ids for v1 indexes; for v2 (PQ)
-indexes — approximate by construction — parity is an MRR@10 delta bound,
-tunable with --parity-mrr-tol (default 0.02).
+indexes — approximate by construction — an MRR@10 delta bound against the
+float32 corpus, tunable with --parity-mrr-tol (default 0.02), and, for
+the serving path alone, rank-wise agreement of the top-10 fused scores
+with the index's own PQ codes scored in memory (the jnp ADC path of
+core/quant.py).
 
 --trace-out exports per-batch stage-span traces (stage1 -> stage2_select
 -> cache/disk fetch -> fused_score_topk; `.jsonl` span lines or Chrome
@@ -92,12 +95,47 @@ import time
 import jax
 import numpy as np
 
-from repro.configs import get_config
+from repro.common.compile_cache import place_compile_cache
+from repro.configs import clusd_msmarco, get_config
 from repro.core import clusd as cl
 from repro.core import disk as dk
 from repro.core import train_lstm as tl
 from repro.data import mrr_at, recall_at, synth_corpus, synth_queries
 from repro.engine import DiskStore, RetrievalEngine
+
+
+def check_results(ids, scores, n_docs):
+    """Sanity of a served result list: every id a real doc, no doc twice
+    in a row, every score finite. Returns the problems found (empty when
+    the results are well formed)."""
+    ids = np.asarray(ids)
+    problems = []
+    if ((ids < 0) | (ids >= n_docs)).any():
+        problems.append(f"{int(((ids < 0) | (ids >= n_docs)).sum())} ids "
+                        f"outside [0, {n_docs})")
+    srt = np.sort(ids, axis=1)
+    dup_rows = int((srt[:, 1:] == srt[:, :-1]).any(axis=1).sum())
+    if dup_rows:
+        problems.append(f"{dup_rows} rows repeat a doc id")
+    if not np.isfinite(np.asarray(scores)).all():
+        problems.append("non-finite scores")
+    return problems
+
+
+def device_bytes_report(index):
+    """One line naming the index arrays a serving engine holds on the
+    device and their bytes."""
+    parts = {"embeddings": index.embeddings,
+             "postings": index.sparse_index,
+             "pq": index.quantizer,
+             "cluster tables": (index.cluster_docs, index.doc_cluster,
+                                index.centroids, index.neighbor_ids,
+                                index.neighbor_sims, index.bin_ids)}
+    sizes = {k: sum(x.nbytes for x in jax.tree.leaves(v))
+             for k, v in parts.items()}
+    total = sum(sizes.values())
+    return ", ".join([f"index {total / 2**30:.3f} GiB"] + [
+        f"{k} {v / 2**30:.3f} GiB" for k, v in sizes.items() if v])
 
 
 def _apply_hybrid_flags(cfg, args):
@@ -266,10 +304,26 @@ def serve_from_router(args, reader, cfg, index, test_q):
     return 0 if ok else 1
 
 
+def _corpus_as_built(reader):
+    """True while the served documents are still those of the generation-0
+    build, so the synthetic-corpus recipe reproduces them: selector
+    publishes add generations but rewrite no corpus array or block shard
+    (index deltas and compactions do)."""
+    if reader.generation == 0:
+        return True
+    from repro.index import format as fmt
+    try:
+        g0 = fmt.load_manifest(reader.index_dir, generation=0)
+    except fmt.IndexFormatError:        # compaction dropped generation 0
+        return False
+    return all(g0.get(k) == reader.manifest.get(k)
+               for k in ("arrays", "block_shards"))
+
+
 def serve_from_index(args):
     """Serve a persistent index built by repro.launch.build_index."""
     from repro import index as index_lib
-    from repro.engine import InMemoryStore, pipeline as pipe_lib
+    from repro.engine import InMemoryStore, PQStore, pipeline as pipe_lib
 
     t0 = time.perf_counter()
     reader = index_lib.IndexReader.open(args.index_dir, verify=args.verify)
@@ -295,16 +349,18 @@ def serve_from_index(args):
                        explain=_make_explain(args)) as engine:
         exporter, slo = _start_exporter(args, engine)
         t1 = time.perf_counter()
-        first_ids, _ = engine.retrieve(
+        first_ids, first_scores = engine.retrieve(
             test_q.q_dense[:args.batch], test_q.q_terms[:args.batch],
             test_q.q_weights[:args.batch])
         first_ms = (time.perf_counter() - t1) * 1e3
         all_ids = [np.asarray(first_ids)]
+        all_scores = [np.asarray(first_scores)]
         for i in range(args.batch, args.queries, args.batch):
-            ids, _ = engine.retrieve(test_q.q_dense[i:i + args.batch],
-                                     test_q.q_terms[i:i + args.batch],
-                                     test_q.q_weights[i:i + args.batch])
+            ids, scores = engine.retrieve(test_q.q_dense[i:i + args.batch],
+                                          test_q.q_terms[i:i + args.batch],
+                                          test_q.q_weights[i:i + args.batch])
             all_ids.append(np.asarray(ids))
+            all_scores.append(np.asarray(scores))
 
         def _replay(deadline):
             for i in range(0, args.queries, args.batch):
@@ -323,16 +379,26 @@ def serve_from_index(args):
           f"{reader.manifest['total_bytes'] / 2**20:.1f} MiB, "
           f"{len(reader.manifest['block_shards'])} shard(s), verify={args.verify})")
     print(f"cold open {open_ms:.0f} ms, first batch {first_ms:.0f} ms "
-          f"(incl. compile)")
+          f"(incl. compile), steady {st.get('mean_ms', float('nan'))} ms/"
+          f"batch of {args.batch}")
+    print(f"on device: {device_bytes_report(engine.index)}")
     print(f"served {args.queries} queries: "
           f"MRR@10={mrr_at(ids, test_q.rel_doc):.4f}, "
           f"{io.get('n_ops', 0)} I/O ops, "
           f"{io.get('bytes', 0) / 2**20:.1f} MiB read, "
-          f"cache hit rate {cache.get('hit_rate', 0.0):.2f}")
+          f"cache hit rate {cache.get('hit_rate', 0.0):.2f}, "
+          f"use_adc={st.get('use_adc')}, "
+          f"prefetch_errors={st['prefetch_errors']}")
     _write_obs(args, engine)
+    problems = check_results(ids, np.concatenate(all_scores),
+                             index.n_docs)
+    if problems or st["prefetch_errors"]:
+        print(f"FAIL: {'; '.join(problems) or ''} "
+              f"prefetch_errors={st['prefetch_errors']}")
+        return 1
 
     if args.check_parity:
-        if reader.generation > 0:
+        if not _corpus_as_built(reader):
             print("PARITY UNAVAILABLE: this index has been incrementally "
                   f"updated (generation {reader.generation}); the "
                   "synthetic-corpus recipe no longer reproduces its "
@@ -340,34 +406,85 @@ def serve_from_index(args):
                   "Use repro.launch.update_index --check-parity (compares "
                   "against a compacted copy) instead.")
             return 1
-        mem = InMemoryStore(corpus.embeddings, index.cluster_docs)
-        ref_ids, _, _ = pipe_lib.retrieve(
-            cfg, index, mem, test_q.q_dense[:args.queries],
-            test_q.q_terms[:args.queries], test_q.q_weights[:args.queries])
-        if reader.is_pq:
-            # PQ serving is approximate by construction: parity is a
-            # bounded MRR@10 delta vs the float32 in-memory backend
-            ref_mrr = mrr_at(np.asarray(ref_ids),
-                             test_q.rel_doc[:args.queries])
-            got_mrr = mrr_at(ids, test_q.rel_doc[:args.queries])
-            if abs(ref_mrr - got_mrr) > args.parity_mrr_tol:
-                print(f"PARITY FAIL: PQ MRR@10 {got_mrr:.4f} vs in-memory "
-                      f"{ref_mrr:.4f} (tol {args.parity_mrr_tol})")
+        def reference(store):
+            # one serving batch at a time: the in-memory gather holds
+            # (batch, max_selected * cap, dim) floats
+            out = [pipe_lib.retrieve(cfg, index, store,
+                                     test_q.q_dense[i:i + args.batch],
+                                     test_q.q_terms[i:i + args.batch],
+                                     test_q.q_weights[i:i + args.batch])
+                   for i in range(0, args.queries, args.batch)]
+            return (np.concatenate([np.asarray(o[0]) for o in out]),
+                    np.concatenate([np.asarray(o[1]) for o in out]))
+
+        # the float32 corpus in memory: exact ids for v1; for v2 (PQ,
+        # approximate by construction) a bounded MRR@10 delta
+        with jax.default_matmul_precision("highest"):
+            ref_ids, _ = reference(jax.device_put(InMemoryStore(
+                corpus.embeddings, index.cluster_docs)))
+        if not reader.is_pq:
+            if not np.array_equal(ids, ref_ids):
+                bad = int((ids != ref_ids).any(axis=1).sum())
+                print(f"PARITY FAIL: {bad}/{args.queries} queries differ "
+                      f"from the in-memory pipeline")
                 return 1
-            print(f"parity OK: PQ MRR@10 {got_mrr:.4f} within "
-                  f"{args.parity_mrr_tol} of in-memory {ref_mrr:.4f}")
-        elif not np.array_equal(ids, np.asarray(ref_ids)):
-            bad = int((ids != np.asarray(ref_ids)).any(axis=1).sum())
-            print(f"PARITY FAIL: {bad}/{args.queries} queries differ from "
-                  f"the in-memory pipeline")
-            return 1
-        else:
             print("parity OK: sharded on-disk serving matches the "
                   "in-memory pipeline exactly")
+            return 0
+        ref_mrr = mrr_at(ref_ids, test_q.rel_doc[:args.queries])
+        got_mrr = mrr_at(ids, test_q.rel_doc[:args.queries])
+        ok = abs(ref_mrr - got_mrr) <= args.parity_mrr_tol
+        print(f"parity {'OK' if ok else 'FAIL'} (float corpus): PQ MRR@10 "
+              f"{got_mrr:.4f} vs in-memory float32 {ref_mrr:.4f} "
+              f"(tol {args.parity_mrr_tol})")
+        # and the serving path alone: the index's own PQ codes scored in
+        # memory by the jnp ADC path (core/quant.py, an f32 LUT like the
+        # kernel's). ADC reassociates the sum, so ids may swap at near-ties,
+        # but each rank's fused score must agree
+        codes_ids, codes_scores = reference(
+            PQStore(reader.quantizer(), index.cluster_docs))
+        top = min(10, ids.shape[1])
+        n_diff = int((ids[:, :top] != codes_ids[:, :top]).any(axis=1).sum())
+        dev = float(np.abs(np.concatenate(all_scores)[:, :top]
+                           - codes_scores[:, :top]).max())
+        codes_ok = dev <= PQ_SCORE_TOL
+        print(f"parity {'OK' if codes_ok else 'FAIL'} (same PQ codes): "
+              f"{n_diff}/{args.queries} queries differ in the top {top} ids, "
+              f"max top-{top} fused-score deviation {dev:.3g} "
+              f"(tol {PQ_SCORE_TOL})")
+        if not (ok and codes_ok):
+            print("PARITY FAIL")
+            return 1
+        print("parity OK")
     return 0
 
 
-def main():
+# --check-parity on PQ indexes: the largest rank-wise fused-score deviation
+# allowed between the serving path and the same codes scored in memory.
+# Fused scores lie in [0, 1]; f32 rounding moves them by ~1e-6, a LUT built
+# with bf16 MXU passes by ~1e-3.
+PQ_SCORE_TOL = 1e-4
+
+
+def build_cfg(args):
+    """The served config for the build-in-memory path (no --index-dir):
+    --variant smoke keeps the small CPU-sized geometry below; --variant
+    full serves the paper's MS MARCO config (configs/clusd_msmarco.full())
+    at its published widths, cut only by --docs / --clusters."""
+    if args.variant == "full":
+        cfg = clusd_msmarco.from_cli(args)
+    else:
+        a = clusd_msmarco.smoke_sizes(args)
+        cfg = dataclasses.replace(
+            get_config("clusd-msmarco", "smoke"),
+            n_docs=a["docs"], dim=a["dim"], n_clusters=a["clusters"],
+            vocab=a["vocab"], k_sparse=512, bins=(10, 25, 50, 100, 200, 512),
+            n_candidates=32, max_selected=16, k_final=256,
+            train_queries=a["train_queries"], epochs=a["epochs"])
+    return _apply_hybrid_flags(cfg, args)
+
+
+def main(argv=None):
     # __doc__ IS the epilog: the module docstring and --help can never
     # drift apart (CI smoke-tests --help for every repro.launch CLI)
     ap = argparse.ArgumentParser(
@@ -375,12 +492,26 @@ def main():
                     "RetrievalEngine (in-memory, on-disk, or a persistent "
                     "built index).",
         epilog=__doc__, formatter_class=argparse.RawDescriptionHelpFormatter)
-    ap.add_argument("--docs", type=int, default=20000)
-    ap.add_argument("--dim", type=int, default=64)
-    ap.add_argument("--clusters", type=int, default=256)
+    ap.add_argument("--variant", default="smoke", choices=("smoke", "full"),
+                    help="clusd-msmarco config built in memory (no "
+                         "--index-dir): smoke (small geometry) or full (the "
+                         "paper's MS MARCO widths; --docs/--clusters are its "
+                         "only cuts)")
+    ap.add_argument("--docs", type=int, default=None,
+                    help="corpus size (default: 20000 smoke, the config's "
+                         "under full)")
+    ap.add_argument("--dim", type=int, default=None, help="smoke only (64)")
+    ap.add_argument("--clusters", type=int, default=None,
+                    help="cluster count (default: 256 smoke, the config's "
+                         "under full)")
     ap.add_argument("--queries", type=int, default=256)
     ap.add_argument("--batch", type=int, default=32)
-    ap.add_argument("--epochs", type=int, default=40)
+    ap.add_argument("--train-queries", type=int, default=None,
+                    help="selector training queries (default: 512 smoke, "
+                         "the config's under full)")
+    ap.add_argument("--epochs", type=int, default=None,
+                    help="selector epochs (default: 40 smoke, the config's "
+                         "under full)")
     ap.add_argument("--ondisk", action="store_true")
     ap.add_argument("--fusion", default=None, choices=("interp", "rrf"),
                     help="final-list fusion method override (default: the "
@@ -415,9 +546,12 @@ def main():
     ap.add_argument("--check-parity", action="store_true",
                     help="with --index-dir: compare against the in-memory "
                          "pipeline, exit non-zero on mismatch (exact ids "
-                         "for v1; MRR@10 tolerance for PQ/v2 indexes)")
+                         "for v1; for PQ/v2 indexes an MRR@10 tolerance "
+                         "vs the float32 corpus and top-10 score agreement "
+                         "with the same PQ codes in memory)")
     ap.add_argument("--parity-mrr-tol", type=float, default=0.02,
-                    help="allowed MRR@10 delta for PQ-index parity")
+                    help="allowed MRR@10 delta between PQ serving and the "
+                         "float32 corpus")
     ap.add_argument("--trace-out", default=None, metavar="PATH",
                     help="export per-batch stage-span traces after serving "
                          "(.jsonl = one span per line, anything else = "
@@ -450,43 +584,58 @@ def main():
                     help="after the scored pass, keep replaying the query "
                          "set for S more seconds so the live endpoints "
                          "can be scraped under sustained traffic")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    place_compile_cache()
 
     if args.index_dir:
         return serve_from_index(args)
 
-    cfg = dataclasses.replace(
-        get_config("clusd-msmarco", "smoke"),
-        n_docs=args.docs, dim=args.dim, n_clusters=args.clusters,
-        vocab=2048, k_sparse=512, bins=(10, 25, 50, 100, 200, 512),
-        n_candidates=32, max_selected=16, k_final=256,
-        train_queries=512, epochs=args.epochs)
-    cfg = _apply_hybrid_flags(cfg, args)
+    cfg = build_cfg(args)
 
-    print("building corpus + index ...", flush=True)
+    print(f"config {cfg.name} ({args.variant}): {cfg.n_docs} docs x "
+          f"{cfg.dim} dim, N={cfg.n_clusters} cap={cfg.cluster_cap}, "
+          f"vocab={cfg.vocab} max_postings={cfg.max_postings}, "
+          f"k_sparse={cfg.k_sparse} n={cfg.n_candidates} "
+          f"max_selected={cfg.max_selected} k_final={cfg.k_final}, "
+          f"train_queries={cfg.train_queries} epochs={cfg.epochs}",
+          flush=True)
+    t0 = time.perf_counter()
     corpus = synth_corpus(0, cfg.n_docs, cfg.dim, cfg.vocab)
+    t1 = time.perf_counter()
     index = cl.build_index(cfg, jax.random.key(0), corpus.embeddings,
                            corpus.doc_terms, corpus.doc_weights)
+    jax.block_until_ready(index)
+    t2 = time.perf_counter()
     train_q = synth_queries(1, corpus, cfg.train_queries)
     _, feats, labels = tl.make_labels(cfg, index, train_q.q_dense,
                                       train_q.q_terms, train_q.q_weights)
     index.lstm_params, hist = tl.train_selector(
         cfg, jax.random.key(2), np.asarray(feats), np.asarray(labels))
+    t3 = time.perf_counter()
+    print(f"setup: corpus {t1 - t0:.1f}s, index build {t2 - t1:.1f}s, "
+          f"labels + selector training {t3 - t2:.1f}s", flush=True)
     print(f"LSTM trained: loss {hist[0]:.4f} -> {hist[-1]:.4f}", flush=True)
 
     test_q = synth_queries(9, corpus, args.queries)
     engine = RetrievalEngine(
         cfg, index, max_batch=args.batch,
         trace_sample_rate=args.trace_sample_rate if args.trace_out else None)
-    all_ids = []
+    print(f"on device: {device_bytes_report(engine.index)}", flush=True)
+    all_ids, all_scores = [], []
     for i in range(0, args.queries, args.batch):
-        ids, _ = engine.retrieve(test_q.q_dense[i:i + args.batch],
-                                 test_q.q_terms[i:i + args.batch],
-                                 test_q.q_weights[i:i + args.batch])
+        ids, scores = engine.retrieve(test_q.q_dense[i:i + args.batch],
+                                      test_q.q_terms[i:i + args.batch],
+                                      test_q.q_weights[i:i + args.batch])
         all_ids.append(np.asarray(ids))
+        all_scores.append(np.asarray(scores))
     ids = np.concatenate(all_ids)
     st = engine.stats()
     lat = np.asarray(engine.serve_stats.per_query_ms())
+    print(f"first batch {engine.serve_stats.batches[0].ms:.1f} ms "
+          f"(incl. compile), steady {st.get('mean_ms', float('nan'))} ms/"
+          f"batch of {args.batch}, prefetch_errors={st['prefetch_errors']}",
+          flush=True)
+    problems = check_results(ids, np.concatenate(all_scores), cfg.n_docs)
 
     oracle_ids, _ = cl.full_dense_topk(index.embeddings, test_q.q_dense, 64)
     print(f"CluSD   MRR@10={mrr_at(ids, test_q.rel_doc):.4f} "
@@ -497,6 +646,10 @@ def main():
               f"p99={np.percentile(lat, 99):.2f}ms "
               f"(buckets compiled: {st['compiled_buckets']})")
     _write_obs(args, engine)
+    if problems or st["prefetch_errors"]:
+        print(f"FAIL: {'; '.join(problems) or ''} "
+              f"prefetch_errors={st['prefetch_errors']}")
+        return 1
 
     if args.ondisk:
         tmp = tempfile.mkdtemp()

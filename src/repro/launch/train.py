@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import place_compile_cache
 from repro.common.metrics import MetricLogger
 from repro.configs import get_config
 from repro.configs.base import TrainConfig
@@ -45,6 +46,7 @@ def main():
     ap.add_argument("--fail-at", type=int, nargs="*", default=[])
     ap.add_argument("--log", default=None)
     args = ap.parse_args()
+    place_compile_cache()
 
     cfg = get_config(args.arch, args.variant)
     tc = TrainConfig(lr=args.lr, total_steps=args.steps,

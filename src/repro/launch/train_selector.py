@@ -55,6 +55,7 @@ import numpy as np
 
 from repro import index as index_lib
 from repro import train as train_lib
+from repro.common.compile_cache import place_compile_cache
 from repro.data import synth_corpus, synth_queries
 
 
@@ -196,6 +197,7 @@ def main(argv=None):
                          "metrics (.prom/.txt = Prometheus text, else "
                          "JSON)")
     args = ap.parse_args(argv)
+    place_compile_cache()
     if isinstance(args.thetas, str):        # default not routed through type=
         args.thetas = _floats(args.thetas)
     if args.target_recall is not None and args.target_budget is not None:
@@ -388,6 +390,7 @@ def main(argv=None):
         assert gen == report["generation"], (gen, report)
         got = _serve_ids(engine, hold_q, n_check, engine.max_batch)
         engine.close()
+        live_st = engine.stats()
         fresh_reader = index_lib.IndexReader.open(args.index_dir,
                                                   verify=args.verify)
         with fresh_reader.engine(max_batch=max(8, n_check)) as fresh:
@@ -399,9 +402,15 @@ def main(argv=None):
                   f"generation {gen}")
             _finish_obs()
             return 1
+        if live_st["prefetch_errors"]:
+            print(f"FAIL: {live_st['prefetch_errors']} prefetch error(s) "
+                  f"on the live engine")
+            _finish_obs()
+            return 1
         print(f"serve check OK: {n_check} queries, hot reload_selector == "
               f"fresh engine on generation {gen} "
-              f"(selector_reloads={engine.stats()['selector_reloads']})")
+              f"(selector_reloads={live_st['selector_reloads']}, "
+              f"prefetch_errors=0)")
     _finish_obs()
     print(json.dumps({"operating_point": op, "hybrid": hybrid,
                       "publish": report,

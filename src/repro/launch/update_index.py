@@ -40,6 +40,7 @@ import time
 import numpy as np
 
 from repro import index as index_lib
+from repro.common.compile_cache import place_compile_cache
 from repro.index import update as update_lib
 
 
@@ -227,6 +228,7 @@ def main(argv=None):
                     help="dump the serving engine's metrics registry "
                          "(.prom/.txt = Prometheus text, else JSON)")
     args = ap.parse_args(argv)
+    place_compile_cache()
 
     from repro.obs import MetricsRegistry, Tracer, write_metrics, write_trace
     tracer = Tracer(sample_rate=1.0) if args.trace_out else None

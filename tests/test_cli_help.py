@@ -106,3 +106,16 @@ def test_train_help_is_docstring_backed():
         assert flag in out, f"train --help no longer documents {flag}"
     # epilog = module docstring (the restartable-loop description)
     assert "fault-tolerant" in out or "restartable" in out
+
+
+def test_chip_smoke_fails_without_a_tpu():
+    """chip_smoke.py is a chip-only run: on the CPU it must exit non-zero
+    before any phase and never print its success line."""
+    env = dict(os.environ)
+    env["JAX_PLATFORMS"] = "cpu"
+    proc = subprocess.run(
+        [sys.executable, os.path.join(REPO_ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, env=env, cwd=REPO_ROOT, timeout=300)
+    assert proc.returncode != 0
+    assert '"ok": true' not in proc.stdout + proc.stderr
+    assert "no TPU" in proc.stderr

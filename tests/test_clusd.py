@@ -338,3 +338,40 @@ def test_end_to_end_beats_single_retrievers(small_index):
     # partial retrieval: only a fraction of the corpus scanned
     assert float(diag["frac_docs_scanned"].mean()) < 0.5
     index.lstm_params = None
+
+
+def _sparse_build_loop(doc_terms, doc_weights, vocab, max_postings):
+    """The per-posting loop SparseIndex.build replaced: (weight, doc)
+    tuples sorted descending per term, truncated."""
+    lists = [[] for _ in range(vocab)]
+    for d in range(doc_terms.shape[0]):
+        for t, w in zip(doc_terms[d], doc_weights[d]):
+            if t >= 0 and w > 0:
+                lists[int(t)].append((float(w), d))
+    pd = np.full((vocab, max_postings), -1, np.int32)
+    pw = np.zeros((vocab, max_postings), np.float32)
+    truncated = 0
+    for t in range(vocab):
+        lst = sorted(lists[t], reverse=True)
+        truncated += max(0, len(lst) - max_postings)
+        for i, (w, d) in enumerate(lst[:max_postings]):
+            pd[t, i], pw[t, i] = d, w
+    return pd, pw, truncated
+
+
+@pytest.mark.parametrize("max_postings", [3, 8, 64])
+def test_sparse_build_matches_loop(max_postings):
+    """Vectorised SparseIndex.build is bit-identical to the loop, with
+    weight ties (broken doc id descending), repeated terms within a doc,
+    pads, zero weights and truncation."""
+    rng = np.random.default_rng(3)
+    D, T, V = 200, 9, 37
+    terms = rng.integers(-1, V, (D, T)).astype(np.int32)
+    weights = rng.choice(np.asarray([0.0, 0.5, 1.0, 1.25, 2.0], np.float32),
+                         (D, T))
+    weights[::7] = rng.lognormal(0.0, 0.5, (len(weights[::7]), T))
+    sp = sparse_lib.SparseIndex.build(terms, weights, V, max_postings)
+    pd, pw, truncated = _sparse_build_loop(terms, weights, V, max_postings)
+    np.testing.assert_array_equal(np.asarray(sp.postings_docs), pd)
+    np.testing.assert_array_equal(np.asarray(sp.postings_weights), pw)
+    assert sp.truncated_postings == truncated

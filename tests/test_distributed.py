@@ -9,8 +9,7 @@ Two tiers, skipped independently:
     `cluster // (N // n_shards)` rule assigned tail clusters to a
     nonexistent shard and silently dropped their postings)
   * multi-device mesh tests (8 virtual CPU devices via subprocess so the
-    main pytest process keeps its single-device view) — skip on jax
-    builds without jax.sharding.AxisType
+    main pytest process keeps its single-device view)
 """
 
 import dataclasses
@@ -25,26 +24,17 @@ import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
-# mesh tests build via jax.make_mesh(..., axis_types=AxisType.Auto); the
-# pure-host layout/ownership tests below run on any jax
-needs_mesh = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="installed jax lacks jax.sharding.AxisType / make_mesh "
-           "axis_types= (needs jax >= 0.6)")
-
-
 def _run(code):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run([sys.executable, "-c", textwrap.dedent(code)],
                        capture_output=True, text=True, env=env, timeout=600)
     assert r.returncode == 0, f"STDOUT:\n{r.stdout}\nSTDERR:\n{r.stderr}"
     return r.stdout
 
 
-@needs_mesh
 def test_sharded_train_step_matches_single_device():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, dataclasses
@@ -88,7 +78,6 @@ def test_sharded_train_step_matches_single_device():
     assert "OK sharded" in out
 
 
-@needs_mesh
 def test_distributed_clusd_serve_matches_host():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
@@ -136,7 +125,6 @@ def test_distributed_clusd_serve_matches_host():
     assert "OK dist overlap" in out
 
 
-@needs_mesh
 def test_compressed_psum_shardmap():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np
@@ -166,7 +154,6 @@ def test_compressed_psum_shardmap():
     assert "OK compressed psum" in out
 
 
-@needs_mesh
 def test_elastic_checkpoint_restore_new_mesh():
     out = _run("""
         import jax, jax.numpy as jnp, numpy as np, tempfile

@@ -501,3 +501,41 @@ def test_engine_adc_empty_selection(tiny, v2_reader):
         ids, scores = eng.retrieve(qs.q_dense, qt, qw)
     assert np.asarray(ids).shape == (len(np.asarray(qs.q_dense)), eng.k)
     assert not np.isnan(np.asarray(scores)).any()
+
+
+# ---------------------------------------------------------------------------
+# index arrays are jit arguments, never compiled-in constants
+# ---------------------------------------------------------------------------
+
+def _nbytes(*trees):
+    return sum(int(x.nbytes) for t in trees for x in jax.tree.leaves(t))
+
+
+@pytest.mark.parametrize("path", ["device", "host"])
+def test_compiled_programs_take_index_as_arguments(tiny, path):
+    """The engine's compiled stage-1 and device programs report argument
+    bytes covering the index arrays they read: nothing is captured as a
+    constant (a captured array shows up as constant HLO and 0 argument
+    bytes)."""
+    cfg, corpus, index, qs = tiny
+    with tempfile.TemporaryDirectory() as d:
+        store = None if path == "device" else DiskStore.create(
+            os.path.join(d, "blocks.bin"), index.embeddings,
+            index.cluster_docs)
+        eng = RetrievalEngine(cfg, index, store=store, max_batch=4,
+                              prefetch=False)
+        eng.retrieve(qs.q_dense[:4], qs.q_terms[:4], qs.q_weights[:4])
+        qd, qt, qw = qs.q_dense[:4], qs.q_terms[:4], qs.q_weights[:4]
+        stage1 = pipeline.build_stage1_fn(cfg).lower(
+            eng.index, qd, qt, qw).compile()
+        read = _nbytes(eng.index.sparse_index, eng.index.centroids,
+                       eng.index.doc_cluster)
+        assert stage1.memory_analysis().argument_size_in_bytes >= read
+        if path == "device":
+            assert ("device", 4) in eng._fns
+            dev = eng._fns[("device", 4)].lower(
+                eng.index, eng.store, qd, qt, qw).compile()
+            read = _nbytes(eng.store.embeddings, eng.index.sparse_index,
+                           eng.index.cluster_docs, eng.index.centroids)
+            assert dev.memory_analysis().argument_size_in_bytes >= read
+        eng.close()

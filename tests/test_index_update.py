@@ -86,6 +86,22 @@ def _assert_same_artifacts(dir_a, man_a, dir_b, man_b):
         assert a == b, f"shard {s1['file']} differs"
 
 
+def _policy_vectors(index, delta):
+    """What re-clustering sees on disk: a v2 index stores only codes, so
+    existing docs are their PQ decodes and the delta's rows are exact
+    (None for v1, where the stored floats ARE the embeddings)."""
+    q = index.quantizer
+    if q is None:
+        return None
+    codes = np.asarray(q.codes)
+    vecs = quant_lib.decode_code_blocks(q.codebooks, codes, q.rotation)
+    n_new = int((delta.upsert_ids >= len(codes)).sum())
+    vecs = np.concatenate([vecs, np.zeros((n_new, vecs.shape[1]),
+                                          np.float32)])
+    vecs[delta.upsert_ids] = delta.upsert_embeddings
+    return vecs
+
+
 def _run_delta_sequence(tmp_root, seed, format_version, n_deltas=2):
     """Shared property body: random index -> write -> delta sequence on
     disk -> compact; vs the same deltas applied in memory -> write."""
@@ -115,7 +131,8 @@ def _run_delta_sequence(tmp_root, seed, format_version, n_deltas=2):
         if delta.n_upserts == 0:         # delete-only: zero-rewrite
             assert report["bytes_rewritten"] == 0
         ref_index, ref_emb, _ = index_lib.apply_delta_to_index(
-            ref_cfg, ref_index, ref_emb, delta, n_shards=n_shards)
+            ref_cfg, ref_index, ref_emb, delta, n_shards=n_shards,
+            policy_vectors=_policy_vectors(ref_index, delta))
         ref_cfg = dataclasses.replace(ref_cfg, n_docs=ref_index.n_docs)
 
     man_live = index_lib.compact_index(out)

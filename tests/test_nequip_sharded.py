@@ -6,9 +6,7 @@ import subprocess
 import sys
 import textwrap
 
-import jax
 import numpy as np
-import pytest
 
 SRC = os.path.join(os.path.dirname(__file__), "..", "src")
 
@@ -30,15 +28,11 @@ def test_shard_edges_by_owner_preserves_edges():
         assert ((d // n_loc) == sh).all()
 
 
-@pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="installed jax lacks jax.sharding.AxisType / make_mesh "
-           "axis_types= (needs jax >= 0.6)")
 def test_owner_sharded_forward_matches_pjit():
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     env["PYTHONPATH"] = SRC
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     code = """
         import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config

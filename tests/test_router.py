@@ -116,7 +116,8 @@ def test_merge_matches_topk_oracle(seed):
     all_ids = np.concatenate([p[0] for p in parts], axis=1)
     all_ss = np.concatenate([p[1] for p in parts], axis=1)
     M = all_ids.shape[1]                       # max possible multiplicity
-    buf = np.full((B, n_docs * M), -np.inf, np.float32)
+    # at least k slots: a k past the valid entries pads with sentinels
+    buf = np.full((B, max(n_docs * M, k)), -np.inf, np.float32)
     for b in range(B):
         occ = {}
         for i, s in zip(all_ids[b], all_ss[b]):

@@ -14,14 +14,6 @@ from repro.analysis.hlo import collective_bytes, hlo_cost
 ART = os.path.join(os.path.dirname(__file__), "..", "artifacts", "dryrun")
 
 
-def _cost_analysis_returns_dict():
-    """Old jax returns cost_analysis() as a one-element list of dicts;
-    the trip-count test indexes it as a dict (jax >= 0.5 API)."""
-    comp = jax.jit(lambda x: x + 1.0).lower(
-        jax.ShapeDtypeStruct((1,), jnp.float32)).compile()
-    return isinstance(comp.cost_analysis(), dict)
-
-
 def test_cell_enumeration_is_40():
     all_cells = cells(include_skipped=True)
     assert len(all_cells) == 40  # 10 archs x 4 shapes
@@ -48,10 +40,6 @@ def test_dryrun_artifacts_complete_and_green():
     assert ok == 72 and skip == 8  # 36 runnable cells x 2 meshes
 
 
-@pytest.mark.skipif(
-    not _cost_analysis_returns_dict(),
-    reason="installed jax returns compiled cost_analysis() as a list "
-           "(dict form needs jax >= 0.5)")
 def test_hlo_parser_counts_loop_trips():
     L, d = 6, 64
 
