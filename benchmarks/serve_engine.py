@@ -172,7 +172,6 @@ def run():
         # ADC serving: raw codes scored in-kernel, zero host decode
         "use_adc": ps["use_adc"],
         "adc_ms": ps.get("adc_ms", 0.0),
-        "lut_build_ms": ps.get("lut_build_ms", 0.0),
         "decode_ms": ps.get("decode_ms", 0.0),
     }
     rows.append(pq_row)

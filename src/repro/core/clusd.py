@@ -76,6 +76,7 @@ def full_dense_topk(embeddings, q_dense, k):
     return i.astype(jnp.int32), s
 
 
+@jax.named_scope("stage1")
 def stage1_candidates(cfg, index, q_dense, sparse_ids, sparse_scores, *,
                       stage1="overlap"):
     """Step 1: sparse-overlap features -> ordered candidate clusters.
@@ -103,6 +104,7 @@ def stage1_candidates(cfg, index, q_dense, sparse_ids, sparse_scores, *,
     return {"cand": cand, "feats": feats, "qc_sim": qc_sim, "P": P, "Q": Q}
 
 
+@jax.named_scope("selector")
 def stage2_select(cfg, index, cand, feats, *, selector="lstm", theta=None,
                   use_kernel=False, selector_params=None):
     """Step 2: selector probabilities -> thresholded, budgeted selection."""

@@ -75,6 +75,7 @@ def side_contrib(scores, mask, weight, method, rrf_k):
                      f"expected one of {FUSION_METHODS}")
 
 
+@jax.named_scope("fuse_topk")
 def fuse_topk(sparse_ids, sparse_scores, dense_ids, dense_scores, dense_mask,
               n_docs, alpha, k, *, sparse_mask=None, method="interp",
               rrf_k=60.0):

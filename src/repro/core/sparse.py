@@ -83,6 +83,7 @@ def sparse_retrieve(index: SparseIndex, q_terms, q_weights, k):
     return top_ids.astype(jnp.int32), top_scores, scores
 
 
+@jax.named_scope("sparse_topk")
 def sparse_retrieve_topk(index: SparseIndex, q_terms, q_weights, k):
     ids, scores, _ = sparse_retrieve(index, q_terms, q_weights, k)
     return ids, scores

@@ -50,16 +50,21 @@ from repro.obs import NOOP_TRACE
 # stage does not read are pruned from its executable by jit. Callers key
 # the fns per request bucket and drop them when `cfg` moves (selector
 # publishes for stage2 and the fused tails).
+#
+# Each program is named (`jit_clusd_<stage>` on a profile) and the core
+# functions it calls wrap their bodies in `jax.named_scope`s (sparse_topk,
+# stage1, selector, dense_score, fuse_topk), so a device trace can split
+# any program's time by stage; scopes are metadata and compile to nothing.
 
 def build_stage1_fn(cfg):
     """Sparse retrieval + Stage-I candidate generation.
     fn(index, qd, qt, qw) -> (sparse_ids, sparse_scores, cand, feats)."""
-    def run(index, qd, qt, qw):
+    def clusd_stage1(index, qd, qt, qw):
         sid, ss = sparse_lib.sparse_retrieve_topk(
             index.sparse_index, qt, qw, cfg.k_sparse)
         s1 = clusd_lib.stage1_candidates(cfg, index, qd, sid, ss)
         return sid, ss, s1["cand"], s1["feats"]
-    return jax.jit(run)
+    return jax.jit(clusd_stage1)
 
 
 def build_stage2_fn(cfg):
@@ -68,32 +73,35 @@ def build_stage2_fn(cfg):
     raw per-candidate selector probabilities (explain telemetry compares
     them against theta/budget; they are computed anyway, so returning them
     is free). Only `index.lstm_params` reaches the executable."""
-    def run(index, cand, feats):
+    def clusd_stage2(index, cand, feats):
         s2 = clusd_lib.stage2_select(cfg, index, cand, feats)
         return s2["sel_ids"], s2["sel_mask"], s2["probs"]
-    return jax.jit(run)
+    return jax.jit(clusd_stage2)
 
 
 def build_lut_fn():
     """Per-query ADC LUT build (OPQ rotation folded in).
     fn(codebooks, rotation, qd) -> (B, nsub, 256) float32."""
-    return jax.jit(lambda codebooks, rotation, qd:
-                   adc_ops.adc_tables(qd, codebooks, rotation))
+    def clusd_lut(codebooks, rotation, qd):
+        with jax.named_scope("dense_score"):
+            return adc_ops.adc_tables(qd, codebooks, rotation)
+    return jax.jit(clusd_lut)
 
 
 def build_device_fn(cfg, *, k):
     """The whole pipeline in one program for device stores (InMemoryStore,
     PQStore). fn(index, store, qd, qt, qw) -> (ids, scores, n_selected)."""
-    def run(index, store, qd, qt, qw):
+    def clusd_device_pipeline(index, store, qd, qt, qw):
         ids, scores, diag = retrieve(cfg, index, store, qd, qt, qw, k=k)
         return ids, scores, diag["n_selected"]
-    return jax.jit(run)
+    return jax.jit(clusd_device_pipeline)
 
 
 # ---------------------------------------------------------------------------
 # dense scoring of selected clusters
 # ---------------------------------------------------------------------------
 
+@jax.named_scope("dense_score")
 def score_selected(store, q_dense, sel_ids, sel_mask):
     """Device-store scoring (jit-traceable).
 
@@ -180,21 +188,24 @@ def build_fused_scorer(cfg, n_docs, *, k, mode):
     alpha, method, rrf_k = cfg.alpha, cfg.fusion, cfg.rrf_k
 
     def run(cluster_docs, q_or_lut, sid, ss, sel_ids, sel_mask, blocks, pos):
-        docs = jnp.take(cluster_docs, sel_ids, axis=0)         # (B, S, cap)
-        B, S, cap = docs.shape
-        valid = (docs >= 0) & sel_mask[:, :, None]
-        if mode == "adc":
-            scores3 = adc_ops.adc_score_blocks(q_or_lut, blocks, pos)
-        else:
-            vecs = jnp.take(blocks, pos, axis=0)               # (B,S,cap,dim)
-            scores3 = jnp.einsum("bd,bscd->bsc", q_or_lut, vecs)
-        vf = valid.reshape(B, S * cap)
-        dscore = jnp.where(vf, scores3.reshape(B, S * cap), 0.0)
-        did = jnp.where(valid, docs, 0).reshape(B, S * cap).astype(jnp.int32)
+        with jax.named_scope("dense_score"):
+            docs = jnp.take(cluster_docs, sel_ids, axis=0)     # (B, S, cap)
+            B, S, cap = docs.shape
+            valid = (docs >= 0) & sel_mask[:, :, None]
+            if mode == "adc":
+                scores3 = adc_ops.adc_score_blocks(q_or_lut, blocks, pos)
+            else:
+                vecs = jnp.take(blocks, pos, axis=0)           # (B,S,cap,dim)
+                scores3 = jnp.einsum("bd,bscd->bsc", q_or_lut, vecs)
+            vf = valid.reshape(B, S * cap)
+            dscore = jnp.where(vf, scores3.reshape(B, S * cap), 0.0)
+            did = jnp.where(valid, docs, 0).reshape(B, S * cap) \
+                .astype(jnp.int32)
         return fusion_lib.fuse_topk(sid, ss, did, dscore, vf,
                                     n_docs, alpha, k,
                                     method=method, rrf_k=rrf_k)
 
+    run.__name__ = run.__qualname__ = f"clusd_fused_{mode}"
     return jax.jit(run)
 
 

@@ -74,7 +74,8 @@ from repro.engine import pipeline as pipe_lib
 from repro.engine import stores as stores_lib
 from repro.engine.cache import BlockCache
 from repro.engine.server import (ServeStats, _pad_rows, bucket_size,
-                                 build_explain_records)
+                                 build_explain_records,
+                                 use_profiler_annotations)
 from repro.core import fusion as fusion_lib
 from repro.kernels import adc as adc_ops
 from repro.obs import NOOP_TRACE, MetricsRegistry, Tracer
@@ -319,13 +320,16 @@ class EngineHost:
         fn = self._fns.get(key)
         if fn is None:
             if mode == "adc":
-                def run(lut, blocks, pos):
-                    return adc_ops.adc_score_blocks(lut, blocks, pos)
+                def clusd_shard_score_adc(lut, blocks, pos):
+                    with jax.named_scope("dense_score"):
+                        return adc_ops.adc_score_blocks(lut, blocks, pos)
+                fn = jax.jit(clusd_shard_score_adc)
             else:
-                def run(q, blocks, pos):
-                    vecs = jnp.take(blocks, pos, axis=0)   # (B, S, cap, dim)
-                    return jnp.einsum("bd,bscd->bsc", q, vecs)
-            fn = jax.jit(run)
+                def clusd_shard_score_dot(q, blocks, pos):
+                    with jax.named_scope("dense_score"):
+                        vecs = jnp.take(blocks, pos, axis=0)  # (B,S,cap,dim)
+                        return jnp.einsum("bd,bscd->bsc", q, vecs)
+                fn = jax.jit(clusd_shard_score_dot)
             self._fns[key] = fn
         return fn
 
@@ -495,6 +499,7 @@ class ShardRouter:
             tracer = Tracer(sample_rate=trace_sample_rate or 0.0)
         elif trace_sample_rate is not None:
             tracer.sample_rate = float(trace_sample_rate)
+        use_profiler_annotations(tracer)
         self.tracer = tracer
         # sampled per-query explain telemetry (repro.obs.ExplainLogger);
         # router records add per-host score attribution (host_contrib)
@@ -588,11 +593,11 @@ class ShardRouter:
         def build():
             cfg, n_docs, k = self.cfg, self.index.n_docs, self.k
 
-            def run(sid, ss, did, dscore, dmask):
+            def clusd_fuse(sid, ss, did, dscore, dmask):
                 return fusion_lib.fuse_topk(
                     sid, ss, did, jnp.where(dmask, dscore, 0.0), dmask,
                     n_docs, cfg.alpha, k, method=cfg.fusion, rrf_k=cfg.rrf_k)
-            return jax.jit(run)
+            return jax.jit(clusd_fuse)
         return self._fn("fuse", (bucket, kd), build)
 
     # -- failover helpers ---------------------------------------------------

@@ -21,9 +21,10 @@ Serving optimizations on top of engine/pipeline.py:
   * ADC serving (`use_adc`, auto-on for code-backed stores): raw PQ codes
     flow disk -> cache -> device and are scored against per-query ADC
     lookup tables (repro.kernels.adc) inside the fused pass — the host
-    never decodes a float block; the LUT is built right after Stage I so
-    it overlaps the Stage-II selection. Timings surface in stats() as
-    `lut_build_ms` / `adc_ms` (and `decode_ms` stays 0 on this path).
+    never decodes a float block; the LUT program is dispatched right
+    after Stage I and runs behind the Stage-II selection, with no sync of
+    its own. stats() reports the fused pass's host wall time as `adc_ms`
+    (and `decode_ms` stays 0 on this path).
 
 Plus zero-downtime index swaps: `reload_index()` hops a serving engine to
 a newer committed index generation (repro.index.update) between batches —
@@ -77,6 +78,19 @@ def _pad_rows(x, n_pad):
     if n_pad == 0:
         return x
     return np.concatenate([x, np.repeat(np.asarray(x)[-1:], n_pad, axis=0)])
+
+
+def _h2d_nbytes(*arrays):
+    """Bytes `jnp.asarray` copies host->device for `arrays`: those not
+    already device arrays."""
+    return sum(int(a.nbytes) for a in arrays if not isinstance(a, jax.Array))
+
+
+def use_profiler_annotations(tracer):
+    """Put the tracer's sampled spans on the JAX profiler's timeline
+    (repro.obs.trace), unless the caller set its own hook."""
+    if tracer.annotate is None:
+        tracer.annotate = jax.profiler.TraceAnnotation
 
 
 def build_explain_records(cfg, *, qid_base, generation, n, cand, probs,
@@ -318,6 +332,7 @@ class RetrievalEngine:
             tracer = Tracer(sample_rate=trace_sample_rate or 0.0)
         elif trace_sample_rate is not None:
             tracer.sample_rate = float(trace_sample_rate)
+        use_profiler_annotations(tracer)
         self.tracer = tracer
         # sampled per-query explain telemetry (repro.obs.ExplainLogger);
         # None (the default) costs a single attribute check per batch.
@@ -325,7 +340,14 @@ class RetrievalEngine:
         # no per-stage host visibility to explain.
         self.explain = explain
         self._adc_ms = self.metrics.counter("serve.adc_ms")
-        self._lut_build_ms = self.metrics.counter("serve.lut_build_ms")
+        # host->device bytes of every batch; the selection's size over the
+        # queries it was counted on (every host-path batch, sampled
+        # device-path batches: reading it there costs a device->host copy)
+        self._h2d_bytes = self.metrics.counter("serve.h2d_bytes")
+        self._clusters_selected = self.metrics.counter(
+            "serve.clusters_selected")
+        self._selected_queries = self.metrics.counter(
+            "serve.selected_queries")
         self._prefetch_enabled = bool(prefetch)
         self._swap_lock = threading.RLock()   # serving vs reload_index
         self._pf_drop = False           # quiesce flag across index swaps
@@ -346,15 +368,11 @@ class RetrievalEngine:
         self._pf_thread = None
         self._start_prefetch()
 
-    # cumulative fused-ADC / LUT-build device time (steady-state only);
+    # cumulative fused-ADC pass wall time (steady-state only);
     # registry-backed so stats(), metrics exports, and reset_stats() agree
     @property
     def adc_ms(self):
         return float(self._adc_ms.value)
-
-    @property
-    def lut_build_ms(self):
-        return float(self._lut_build_ms.value)
 
     # -- lifecycle ----------------------------------------------------------
 
@@ -686,18 +704,22 @@ class RetrievalEngine:
                 jnp.concatenate(out_scores, axis=0))
 
     def _retrieve_chunk(self, q_dense, q_terms, q_weights):
+        n = int(np.asarray(q_dense).shape[0])
+        bucket = bucket_size(n, self.max_batch)
+        tr = self.tracer.trace("batch", size=n, bucket=bucket)
         # one chunk serves entirely on one index generation: reload_index
         # takes the same lock, so swaps land between chunks, never inside
-        with self._swap_lock:
-            n = int(np.asarray(q_dense).shape[0])
-            bucket = bucket_size(n, self.max_batch)
+        with tr.span("lock_wait"):
+            self._swap_lock.acquire()
+        try:
             self._built_fn = False
-            tr = self.tracer.trace("batch", size=n, bucket=bucket)
             with tr.span("pad"):
                 pad = bucket - n
-                qd = jnp.asarray(_pad_rows(q_dense, pad))
-                qt = jnp.asarray(_pad_rows(q_terms, pad))
-                qw = jnp.asarray(_pad_rows(q_weights, pad))
+                qd, qt, qw = (_pad_rows(x, pad)
+                              for x in (q_dense, q_terms, q_weights))
+                self._h2d_bytes.inc(_h2d_nbytes(qd, qt, qw))
+                qd, qt, qw = jnp.asarray(qd), jnp.asarray(qt), \
+                    jnp.asarray(qw)
             # batch_ms starts AFTER input pad/transfer, matching the
             # pre-obs measurement exactly (the `pad` span still shows it)
             t0 = time.perf_counter()
@@ -706,10 +728,14 @@ class RetrievalEngine:
                 ids.block_until_ready()
             else:
                 with tr.span("device_pipeline"):
-                    ids, scores, _ = self._device_fn(bucket)(
-                        self.index, self.store, qd, qt, qw)
-                    ids.block_until_ready()
+                    with tr.span("device_dispatch"):
+                        ids, scores, n_sel = self._device_fn(bucket)(
+                            self.index, self.store, qd, qt, qw)
+                    with tr.region("device_wait"):
+                        ids.block_until_ready()
             ms = (time.perf_counter() - t0) * 1e3
+            if tr is not NOOP_TRACE and not self.is_host:
+                self._count_selected(np.asarray(n_sel)[:n].sum(), n)
             # a batch "compiled" if ANY stage built a new jitted fn for it
             # (stage buckets, but also a first-seen unique-block bucket of
             # the fused tail) — steady-state latency stats exclude those,
@@ -717,6 +743,12 @@ class RetrievalEngine:
             tr.finish(compiled=self._built_fn, batch_ms=round(ms, 3))
             self.serve_stats.record(n, bucket, self._built_fn, ms)
             return ids[:n], scores[:n]
+        finally:
+            self._swap_lock.release()
+
+    def _count_selected(self, n_clusters, n_queries):
+        self._clusters_selected.inc(int(n_clusters))
+        self._selected_queries.inc(int(n_queries))
 
     @staticmethod
     def _pow2(n):
@@ -728,27 +760,30 @@ class RetrievalEngine:
     def _serve_host(self, bucket, qd, qt, qw, tr=NOOP_TRACE, n=None):
         n = bucket if n is None else n
         with tr.span("stage1"):
-            sid, ss, cand, feats = self._stage1_fn(bucket)(self.index,
-                                                           qd, qt, qw)
-            cand_np = np.asarray(cand)      # device sync for Stage I
+            with tr.span("stage1_dispatch"):
+                sid, ss, cand, feats = self._stage1_fn(bucket)(self.index,
+                                                               qd, qt, qw)
+            with tr.region("stage1_wait"):
+                cand_np = np.asarray(cand)      # device sync for Stage I
             # overlap: start pulling candidate blocks while Stage II runs
             # (the enqueue itself is host work, charged to this span)
-            self._enqueue_prefetch(cand_np)
+            with tr.span("prefetch_enqueue"):
+                self._enqueue_prefetch(cand_np)
         lut = None
         if self.use_adc:
-            # the LUT depends only on the queries — build it while the
-            # prefetcher is pulling candidate code blocks
+            # the LUT depends only on the queries: dispatched here, it runs
+            # on the device behind Stage II while the prefetcher pulls
+            # candidate code blocks (the fused tail's call waits for it)
             with tr.span("lut_build"):
-                t0 = time.perf_counter()
                 lut = self._lut_fn(bucket)(*self._codebooks, qd)
-                lut.block_until_ready()
-                if not self._built_fn:   # steady-state only (no compile skew)
-                    self._lut_build_ms.inc((time.perf_counter() - t0) * 1e3)
         with tr.span("stage2_select"):
-            sel_ids, sel_mask, probs = self._stage2_fn(bucket)(self.index,
-                                                               cand, feats)
-            sel_np = np.asarray(sel_ids)    # device sync for Stage II
-            mask_np = np.asarray(sel_mask)
+            with tr.span("stage2_dispatch"):
+                sel_ids, sel_mask, probs = self._stage2_fn(bucket)(
+                    self.index, cand, feats)
+            with tr.region("stage2_wait"):
+                sel_np = np.asarray(sel_ids)    # device sync for Stage II
+                mask_np = np.asarray(sel_mask)
+        self._count_selected(mask_np[:n].sum(), n)
         with tr.span("fuse"):               # host glue: dedup + positions
             uniq, pos = pipe_lib.dedup_selected(sel_np, mask_np)
         if bool(mask_np.any()):
@@ -765,20 +800,25 @@ class RetrievalEngine:
         with tr.span("fused_score_topk"):
             # pad the unique-block axis to a power of two so fused-tail
             # compilations stay bounded (pos only ever indexes real rows)
-            ub = self._pow2(blocks.shape[0])
-            if ub > blocks.shape[0]:
-                blocks = np.concatenate(
-                    [blocks,
-                     np.zeros((ub - blocks.shape[0],) + blocks.shape[1:],
-                              blocks.dtype)])
+            with tr.span("tail_pad"):
+                ub = self._pow2(blocks.shape[0])
+                if ub > blocks.shape[0]:
+                    blocks = np.concatenate(
+                        [blocks,
+                         np.zeros((ub - blocks.shape[0],) + blocks.shape[1:],
+                                  blocks.dtype)])
             kind = "adc" if self.use_adc else "dot"
             fn = self._fused_fn(kind, bucket, ub)
             t0 = time.perf_counter()
-            ids, scores = fn(self.index.cluster_docs,
-                             lut if self.use_adc else qd, sid, ss,
-                             sel_ids, sel_mask, jnp.asarray(blocks),
-                             jnp.asarray(pos))
-            ids.block_until_ready()
+            with tr.span("tail_h2d"):
+                self._h2d_bytes.inc(_h2d_nbytes(blocks, pos))
+                blocks_d, pos_d = jnp.asarray(blocks), jnp.asarray(pos)
+            with tr.span("tail_dispatch"):
+                ids, scores = fn(self.index.cluster_docs,
+                                 lut if self.use_adc else qd, sid, ss,
+                                 sel_ids, sel_mask, blocks_d, pos_d)
+            with tr.region("tail_wait"):
+                ids.block_until_ready()
             if self.use_adc and not self._built_fn:
                 # steady-state only (no compile skew)
                 self._adc_ms.inc((time.perf_counter() - t0) * 1e3)
@@ -848,7 +888,9 @@ class RetrievalEngine:
                 out["decode_ms"] = round(decode_ms, 2)
             if self.use_adc:
                 out["adc_ms"] = round(self.adc_ms, 2)
-                out["lut_build_ms"] = round(self.lut_build_ms, 2)
+        out["h2d_bytes"] = int(self._h2d_bytes.value)
+        out["clusters_selected"] = int(self._clusters_selected.value)
+        out["selected_queries"] = int(self._selected_queries.value)
         return out
 
     def reset_stats(self):
@@ -860,7 +902,8 @@ class RetrievalEngine:
         both `reload_index()` (I/O, decode, and cache counters are carried
         onto the new store/cache) and `reload_selector()`, and reset ONLY
         here. After reset: batch/latency windows, compile-batch history,
-        prefetch/reload counts, adc/LUT/decode times, cache
+        prefetch/reload counts, adc/decode times, h2d bytes and the
+        selection counts, cache
         hit/miss/eviction/clear counts, and store IOStats all read zero;
         the next stats() reflects serving from this instant."""
         with self._swap_lock:

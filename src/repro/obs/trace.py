@@ -28,6 +28,18 @@ oldest dropped and counted) and export as:
   * Chrome trace JSON ({"traceEvents": [...]}, "X" complete events,
     microsecond timestamps) — open in chrome://tracing or Perfetto.
 
+Profiler timeline: a Tracer may carry an `annotate(name) -> context
+manager` hook (the engine sets `jax.profiler.TraceAnnotation`; this
+module imports no jax). When set, every span of a sampled trace, the
+root included, is also entered as a `clusd.<span name>` region on the
+profiler's host timeline, so device programs and the stages that
+dispatched them share one clock. `Trace.region(name)` marks a region on
+that timeline alone, with no span recorded (the engine's `*_wait`
+regions: a span around a wait would hold the device program it waits
+for, and tools that attribute a program to the innermost span covering
+it would take it from the stage span). Unsampled requests (NOOP_TRACE)
+never call the hook; `add_completed` grafts stay off the timeline.
+
 Validated by benchmarks/check_trace.py (CI runs it on a serve trace).
 """
 
@@ -35,12 +47,15 @@ import json
 import threading
 import time
 
+# profiler regions are named "<prefix><span name>"
+ANNOTATION_PREFIX = "clusd."
+
 
 class Span:
     """One timed region. Context manager; closes itself on __exit__."""
 
     __slots__ = ("name", "index", "parent", "depth", "t0_ms", "dur_ms",
-                 "annot", "_trace")
+                 "annot", "_trace", "_region")
 
     def __init__(self, trace, name, index, parent, depth, t0_ms, annot):
         self._trace = trace
@@ -51,6 +66,7 @@ class Span:
         self.t0_ms = t0_ms
         self.dur_ms = None          # open until __exit__/end()
         self.annot = annot
+        self._region = None         # open profiler region, if any
 
     def annotate(self, **kw):
         self.annot.update(kw)
@@ -107,6 +123,9 @@ class _NoopTrace:
     def add_completed(self, name, *, t0_abs, dur_ms, parent=None, **annot):
         return NOOP_SPAN
 
+    def region(self, name):
+        return NOOP_SPAN
+
     def annotate(self, **kw):
         return self
 
@@ -132,8 +151,15 @@ class Trace:
         self._stack = []
         # span 0 is the implicit root covering the whole trace
         root = Span(self, name, 0, -1, 0, 0.0, dict(annot))
+        self._open_region(root)
         self.spans.append(root)
         self._stack.append(root)
+
+    def _open_region(self, sp):
+        hook = self._tracer.annotate
+        if hook is not None:
+            sp._region = hook(ANNOTATION_PREFIX + sp.name)
+            sp._region.__enter__()
 
     def _now_ms(self):
         return (time.perf_counter() - self._t0) * 1e3
@@ -143,9 +169,17 @@ class Trace:
         parent = self._stack[-1] if self._stack else self.spans[0]
         sp = Span(self, name, len(self.spans), parent.index,
                   parent.depth + 1, self._now_ms(), annot)
+        self._open_region(sp)
         self.spans.append(sp)
         self._stack.append(sp)
         return sp
+
+    def region(self, name):
+        """A `clusd.<name>` region on the profiler's timeline that records
+        no span: a context manager from the tracer's hook, or a no-op
+        without one."""
+        hook = self._tracer.annotate
+        return NOOP_SPAN if hook is None else hook(ANNOTATION_PREFIX + name)
 
     def add_completed(self, name, *, t0_abs, dur_ms, parent=None, **annot):
         """Graft an already-measured span under an explicit parent.
@@ -155,7 +189,9 @@ class Trace:
         `time.perf_counter()` at span start, `dur_ms` its duration, and
         `parent` a Span of this trace (default: innermost open span).
         The span is appended CLOSED and never touches the nesting stack,
-        so the calling thread's own span structure is unaffected."""
+        so the calling thread's own span structure is unaffected. It is
+        not entered on the profiler's timeline (the `annotate` hook
+        marks the calling thread's time, and this work ran elsewhere)."""
         if parent is None:
             parent = self._stack[-1] if self._stack else self.spans[0]
         sp = Span(self, name, len(self.spans), parent.index,
@@ -167,6 +203,9 @@ class Trace:
     def _close(self, sp):
         if sp.dur_ms is None:
             sp.dur_ms = self._now_ms() - sp.t0_ms
+            if sp._region is not None:
+                sp._region.__exit__(None, None, None)
+                sp._region = None
         if self._stack and self._stack[-1] is sp:
             self._stack.pop()
 
@@ -194,11 +233,16 @@ class Trace:
 
 class Tracer:
     """Sampling + bounded retention + exporters. Thread-safe at the
-    trace granularity (each Trace itself is single-threaded)."""
+    trace granularity (each Trace itself is single-threaded).
 
-    def __init__(self, sample_rate=0.0, capacity=1024):
+    `annotate`: optional `name -> context manager` hook that puts the
+    spans of sampled traces on a profiler's timeline (module docstring);
+    None keeps spans on the Tracer alone."""
+
+    def __init__(self, sample_rate=0.0, capacity=1024, annotate=None):
         self.sample_rate = float(sample_rate)
         self.capacity = int(capacity)
+        self.annotate = annotate
         self._epoch = time.perf_counter()
         self._lock = threading.Lock()
         self._traces = []           # finished, bounded by capacity

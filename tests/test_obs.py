@@ -322,3 +322,257 @@ def test_engine_span_coverage_of_batch_wall(tiny_engine_parts):
     assert batch_wall > 0
     assert covered / batch_wall >= 0.9, \
         f"spans cover {covered / batch_wall:.0%} of batch wall time"
+
+
+# ---------------------------------------------------------------------------
+# profiler timeline: the Tracer's annotate hook
+# ---------------------------------------------------------------------------
+
+def _recording_hook(events):
+    """An `annotate` hook that records ("enter" | "exit", name)."""
+    import contextlib
+
+    @contextlib.contextmanager
+    def annotate(name):
+        events.append(("enter", name))
+        yield
+        events.append(("exit", name))
+    return annotate
+
+
+def _region_parents(events):
+    """[(region, enclosing region or None)] in entry order; asserts the
+    events nest (every exit closes the innermost open region)."""
+    stack, out = [], []
+    for kind, name in events:
+        if kind == "enter":
+            out.append((name, stack[-1] if stack else None))
+            stack.append(name)
+        else:
+            assert stack and stack[-1] == name, (name, stack)
+            stack.pop()
+    assert stack == []
+    return out
+
+
+def test_annotate_hook_nests_with_spans_and_skips_noop():
+    events = []
+    tracer = Tracer(sample_rate=1.0, annotate=_recording_hook(events))
+    tr = tracer.trace("batch", size=4)
+    with tr.span("stage1"):
+        with tr.span("stage1_dispatch"):
+            pass
+        with tr.region("stage1_wait"):
+            pass
+    tr.add_completed("grafted", t0_abs=time.perf_counter(), dur_ms=1.0)
+    sp = tr.span("fused_score_topk")
+    sp.end()
+    tr.finish()
+    # once per span (root included) plus the region, in nesting order;
+    # the grafted span stays off the timeline and `region` records no span
+    assert _region_parents(events) == [
+        ("clusd.batch", None), ("clusd.stage1", "clusd.batch"),
+        ("clusd.stage1_dispatch", "clusd.stage1"),
+        ("clusd.stage1_wait", "clusd.stage1"),
+        ("clusd.fused_score_topk", "clusd.batch")]
+    assert len(events) == 10
+    assert [s.name for s in tr.spans] == [
+        "batch", "stage1", "stage1_dispatch", "grafted", "fused_score_topk"]
+    # unsampled requests never reach the hook
+    events.clear()
+    tracer.sample_rate = 0.0
+    tr = tracer.trace("batch")
+    assert tr is NOOP_TRACE
+    with tr.span("stage1"):
+        with tr.region("stage1_wait"):
+            pass
+    tr.finish()
+    assert events == []
+    # without a hook a sampled trace records spans and regions are no-ops
+    plain = Tracer(sample_rate=1.0).trace("batch")
+    assert plain.region("x") is NOOP_SPAN
+    plain.finish()
+
+
+def _engine_timeline(eng, batches):
+    """Serve `batches` through `eng` with a recording hook on its tracer;
+    -> the (region, parent) pairs of the last batch."""
+    events = []
+    eng.tracer.annotate = _recording_hook(events)
+    for qd, qt, qw in batches:
+        events.clear()
+        eng.retrieve(qd, qt, qw)
+    return _region_parents(events)
+
+
+def _host_batches(qs, sizes):
+    """Batches of the given sizes, as host arrays, cycling over the 8
+    queries."""
+    out, lo = [], 0
+    for n in sizes:
+        rows = np.arange(lo, lo + n) % 8
+        out.append(tuple(np.asarray(x)[rows] for x in
+                         (qs.q_dense, qs.q_terms, qs.q_weights)))
+        lo += n
+    return out
+
+
+def test_engine_host_path_child_spans_and_counters(tiny_engine_parts,
+                                                   tmp_path):
+    """Host path: the new child spans nest inside their stage spans and
+    the export passes check_trace; the wait regions sit inside their
+    stages on the timeline; serve.h2d_bytes and serve.clusters_selected
+    equal hand counts."""
+    from benchmarks import check_trace
+    from repro.engine import DiskStore, RetrievalEngine
+    from repro.engine import pipeline as pipe_lib
+    cfg, corpus, index, qs = tiny_engine_parts
+    tracer = Tracer(sample_rate=1.0)
+    batches = _host_batches(qs, (5, 8, 3))
+    with tempfile.TemporaryDirectory() as d:
+        store = DiskStore.create(os.path.join(d, "blocks.bin"),
+                                 index.embeddings, index.cluster_docs)
+        with RetrievalEngine(cfg, index, store=store, max_batch=8,
+                             cache_capacity=8, prefetch=False,
+                             tracer=tracer) as eng:
+            regions = _engine_timeline(eng, batches)
+            st = eng.stats()
+            cap, dim = store.cap, store.dim
+    parents = {}
+    for t in tracer.traces:
+        for sp in t.spans[1:]:
+            parents[sp.name] = t.spans[sp.parent].name
+    for child, parent in (("lock_wait", "batch"),
+                          ("stage1_dispatch", "stage1"),
+                          ("prefetch_enqueue", "stage1"),
+                          ("stage2_dispatch", "stage2_select"),
+                          ("tail_pad", "fused_score_topk"),
+                          ("tail_h2d", "fused_score_topk"),
+                          ("tail_dispatch", "fused_score_topk")):
+        assert parents.get(child) == parent, (child, parents.get(child))
+    assert not {"stage1_wait", "stage2_wait", "tail_wait"} & set(parents)
+    jp = str(tmp_path / "host.jsonl")
+    write_trace(tracer, jp)
+    assert check_trace.main([jp, "--require-spans",
+                             "lock_wait,tail_h2d,stage2_dispatch"]) == 0
+    assert ("clusd.stage1_wait", "clusd.stage1") in regions
+    assert ("clusd.stage2_wait", "clusd.stage2_select") in regions
+    assert ("clusd.tail_wait", "clusd.fused_score_topk") in regions
+    assert ("clusd.lock_wait", "clusd.batch") in regions
+    # hand counts: padded query inputs, the pow2-padded unique blocks and
+    # pos of every batch; the selection over the real queries
+    stage1, stage2 = pipe_lib.build_stage1_fn(cfg), \
+        pipe_lib.build_stage2_fn(cfg)
+    h2d = selected = queries = 0
+    for qd, qt, qw in batches:
+        n = len(qd)
+        pad = (1 << (n - 1).bit_length()) - n          # pow2 bucket
+        qd, qt, qw = (np.concatenate([x, np.repeat(x[-1:], pad, 0)])
+                      for x in (qd, qt, qw))
+        h2d += qd.nbytes + qt.nbytes + qw.nbytes
+        _, _, cand, feats = stage1(eng.index, qd, qt, qw)
+        sel, mask, _ = stage2(eng.index, cand, feats)
+        sel, mask = np.asarray(sel), np.asarray(mask)
+        u = len(np.unique(sel[mask])) if mask.any() else 1
+        ub = 1 << (u - 1).bit_length()
+        h2d += ub * cap * dim * 4 + sel.size * 4
+        selected += int(mask[:n].sum())
+        queries += n
+    assert st["h2d_bytes"] == h2d
+    assert st["clusters_selected"] == selected > 0
+    assert st["selected_queries"] == queries == 16
+
+
+def test_engine_device_path_child_spans_and_counters(tiny_engine_parts,
+                                                     tmp_path):
+    """Device path: `device_dispatch` nests in `device_pipeline` and the
+    `device_wait` region inside it; h2d counts the padded query inputs of
+    every batch; the selection is counted on sampled batches only."""
+    from benchmarks import check_trace
+    from repro.engine import RetrievalEngine
+    from repro.engine import pipeline as pipe_lib
+    cfg, corpus, index, qs = tiny_engine_parts
+    tracer = Tracer(sample_rate=0.5)
+    batches = _host_batches(qs, (5, 8, 3, 6))
+    with RetrievalEngine(cfg, index, max_batch=8, tracer=tracer) as eng:
+        regions = _engine_timeline(eng, batches)
+        st = eng.stats()
+        fn = pipe_lib.build_device_fn(cfg, k=eng.k)
+        h2d = selected = queries = 0
+        for i, (qd, qt, qw) in enumerate(batches):
+            n = len(qd)
+            pad = (1 << (n - 1).bit_length()) - n      # pow2 bucket
+            qd, qt, qw = (np.concatenate([x, np.repeat(x[-1:], pad, 0)])
+                          for x in (qd, qt, qw))
+            h2d += qd.nbytes + qt.nbytes + qw.nbytes
+            if i % 2:                   # rate 0.5: every second batch
+                _, _, n_sel = fn(eng.index, eng.store, qd, qt, qw)
+                selected += int(np.asarray(n_sel)[:n].sum())
+                queries += n
+    assert [t.spans[0].annot["size"] for t in tracer.traces] == [8, 6]
+    for t in tracer.traces:
+        names = {sp.name: t.spans[sp.parent].name for sp in t.spans[1:]}
+        assert names["device_dispatch"] == "device_pipeline"
+        assert names["lock_wait"] == "batch"
+    assert ("clusd.device_wait", "clusd.device_pipeline") in regions
+    jp = str(tmp_path / "device.jsonl")
+    write_trace(tracer, jp)
+    assert check_trace.main([jp, "--require-spans",
+                             "device_dispatch,lock_wait"]) == 0
+    assert st["h2d_bytes"] == h2d
+    assert st["clusters_selected"] == selected > 0
+    assert st["selected_queries"] == queries == 14
+
+
+def _stage_program(name, cfg, index, qs):
+    """(jitted program, its arguments) at the tiny engine's shapes."""
+    from repro.engine import pipeline as pipe_lib
+    from repro.engine import stores as stores_lib
+    qd, qt, qw = (np.asarray(x) for x in
+                  (qs.q_dense, qs.q_terms, qs.q_weights))
+    B, S, cap = len(qd), cfg.max_selected, index.cluster_docs.shape[1]
+    nsub = 4
+    sid = np.zeros((B, cfg.k_sparse), np.int32)
+    ss = np.zeros((B, cfg.k_sparse), np.float32)
+    sel = np.zeros((B, S), np.int32)
+    mask = np.ones((B, S), bool)
+    pos = np.zeros((B, S), np.int32)
+    fused = [index.cluster_docs, None, sid, ss, sel, mask, None, pos]
+    if name == "stage1":
+        return pipe_lib.build_stage1_fn(cfg), (index, qd, qt, qw)
+    if name == "stage2":
+        _, _, cand, feats = pipe_lib.build_stage1_fn(cfg)(index, qd, qt, qw)
+        return pipe_lib.build_stage2_fn(cfg), (index, cand, feats)
+    if name == "lut":
+        books = np.zeros((nsub, 256, cfg.dim // nsub), np.float32)
+        return pipe_lib.build_lut_fn(), (books, None, qd)
+    if name == "device_pipeline":
+        return (pipe_lib.build_device_fn(cfg, k=cfg.k_final),
+                (index, stores_lib.store_for_index(index), qd, qt, qw))
+    mode = name.rsplit("_", 1)[1]
+    fused[1] = np.zeros((B, nsub, 256), np.float32) if mode == "adc" else qd
+    fused[6] = np.zeros((2, cap, nsub), np.uint8) if mode == "adc" \
+        else np.zeros((2, cap, cfg.dim), np.float32)
+    return (pipe_lib.build_fused_scorer(cfg, index.n_docs, k=cfg.k_final,
+                                        mode=mode), tuple(fused))
+
+
+@pytest.mark.parametrize("name,scopes", [
+    ("stage1", ("sparse_topk", "stage1")),
+    ("stage2", ("selector",)),
+    ("lut", ("dense_score",)),
+    ("device_pipeline", ("sparse_topk", "stage1", "selector",
+                         "dense_score", "fuse_topk")),
+    ("fused_adc", ("dense_score", "fuse_topk")),
+    ("fused_dot", ("dense_score", "fuse_topk")),
+])
+def test_stage_programs_are_named_and_scoped(tiny_engine_parts, name,
+                                             scopes):
+    """Every stage program compiles as `jit(clusd_<stage>)` and its ops
+    carry the stage scopes in their op_name metadata."""
+    cfg, _, index, qs = tiny_engine_parts
+    fn, args = _stage_program(name, cfg, index, qs)
+    text = fn.lower(*args).compile().as_text()
+    for scope in scopes:
+        assert f'op_name="jit(clusd_{name})/{scope}/' in text, (name, scope)
+    assert "jit(run)" not in text
