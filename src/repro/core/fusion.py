@@ -21,15 +21,36 @@ Masked entries contribute 0 AND are excluded from the min-max range /
 rank assignment — a padded or ragged sparse list must not skew the
 normalization of the real entries.
 
-Two formulations, same semantics (property-tested against each other at
-arbitrary id multiplicity in tests/test_clusd.py):
+Two formulations, same answers (tests/test_clusd.py holds them to each
+other at arbitrary id multiplicity):
 
-  fuse_topk        O(n_docs) scatter-add oracle (exact, jit-able).
-  fuse_topk_merge  sort-merge without the O(n_docs) buffer — duplicates
-      are folded by a segment-sum over the id-sorted entries, so a doc id
-      may appear ANY number of times across (and within) the two lists;
-      the distributed serving path and graph-expanded candidate lists
-      both produce multiplicity > 2.
+  fuse_topk          serves. No scatter and no n_docs-wide array: one
+      stable sort of the candidate entries by id, a loop that sums each
+      run of equal ids left to right (one pass per entry beyond a run's
+      first), and a sort of the run ends by (score desc, id asc), of
+      which it keeps the first k. A doc id may appear ANY number of times
+      across (and within) the two lists.
+  fuse_topk_scatter  the tests' oracle: an (n_docs + 1) buffer per query,
+      scatter-add every entry's contribution, then top-k over the whole
+      corpus.
+
+Every document outside the candidates scores exactly 0 (both methods'
+contributions are >= 0), so `fuse_topk` adds k fill entries, ids 0..k-1
+with contribution 0: they stand for the buffer's zero-score tail, of
+which the lowest ids rank first. It needs k <= n_docs, as the buffer's
+top-k does. Ties go to the lower id by the second sort key, not by the
+order in which a top-k happens to leave equal scores (a TPU v5e's
+`lax.top_k` over a 2^21-long row was seen to leave them out of index
+order).
+
+Sum order: the sort is stable and the dense entries precede the sparse
+ones, so a doc on both sides sums dc + sc, the same float adds as the
+buffer's 0 + dc + sc, and the scores are bitwise equal. A doc repeated
+within one side is summed in list order; XLA leaves the order of
+duplicate updates inside one scatter open, so there the two may differ
+by a few ulp. `fuse_topk_merge` is the same merge with a caller-given
+sentinel and no fill entries (the distributed path): sentinel ids and
+-inf past the live entries.
 """
 
 import jax
@@ -75,20 +96,106 @@ def side_contrib(scores, mask, weight, method, rrf_k):
                      f"expected one of {FUSION_METHODS}")
 
 
-@jax.named_scope("fuse_topk")
-def fuse_topk(sparse_ids, sparse_scores, dense_ids, dense_scores, dense_mask,
-              n_docs, alpha, k, *, sparse_mask=None, method="interp",
-              rrf_k=60.0):
-    """Union-merge + fuse + global top-k (exact scatter formulation).
-
-    sparse_ids/scores: (B, Ks) with optional sparse_mask for padding;
-    dense_ids/scores: (B, Kd) with dense_mask for padding. Returns
-    (ids (B, k), fused scores (B, k)).
-    """
+def _contribs(sparse_ids, sparse_scores, dense_scores, dense_mask, alpha,
+              method, rrf_k, sparse_mask):
     if sparse_mask is None:
         sparse_mask = jnp.ones_like(sparse_ids, bool)
     s_c = side_contrib(sparse_scores, sparse_mask, alpha, method, rrf_k)
     d_c = side_contrib(dense_scores, dense_mask, 1.0 - alpha, method, rrf_k)
+    return sparse_mask, s_c, d_c
+
+
+def _shift(x, fill):
+    """x shifted one entry right along axis 1, `fill` entering at 0."""
+    return jnp.concatenate([jnp.full_like(x[:, :1], fill), x[:, :-1]],
+                           axis=1)
+
+
+def _run_sums(ids_s, c_s, sentinel):
+    """At every live entry of the id-sorted (B, L) list, the sum of its
+    run of equal ids up to and including it, added left to right as the
+    buffer's scatter adds them. Each pass extends every sum by one earlier
+    entry of its run, so the loop runs (longest live run - 1) passes: one
+    at serving, where a doc is at most once on each side. The dead entries
+    (ids >= sentinel, tens of thousands of masked slots) form no run.
+    Returns (sums (B, L), passes made)."""
+    # entry i continues i - 1's run
+    same = (ids_s == _shift(ids_s, -1)) & (ids_s < sentinel)
+
+    def step(state):
+        total, open_, passes = state   # open_: the sum at i lacks an entry
+        return (jnp.where(same, _shift(total, 0.0) + c_s, c_s),
+                same & _shift(open_, False), passes + 1)
+
+    total, _, passes = jax.lax.while_loop(lambda st: jnp.any(st[1]), step,
+                                          (c_s, same, jnp.int32(0)))
+    return total, passes
+
+
+def _entries(dense_ids, dense_mask, d_c, sparse_ids, sparse_mask, s_c,
+             sentinel, n_fill):
+    """The (B, L) merge list: (ids, contributions) of the dense entries,
+    then the sparse ones, then n_fill zero entries with ids 0..n_fill-1;
+    a masked entry takes the sentinel id."""
+    B = dense_ids.shape[0]
+    fill = jnp.arange(n_fill, dtype=jnp.int32)
+    ids = jnp.concatenate([
+        jnp.where(dense_mask, dense_ids, sentinel).astype(jnp.int32),
+        jnp.where(sparse_mask, sparse_ids, sentinel).astype(jnp.int32),
+        jnp.broadcast_to(fill, (B, n_fill))], axis=1)
+    contrib = jnp.concatenate([d_c.astype(jnp.float32),
+                               s_c.astype(jnp.float32),
+                               jnp.zeros((B, n_fill), jnp.float32)], axis=1)
+    return ids, contrib
+
+
+def _merge_topk(ids, contrib, k, sentinel):
+    """Top-k by (score desc, id asc) over the distinct ids of (B, L)
+    entries, each id's score the sum of its entries' contributions; ids
+    >= sentinel are dead. Returns (ids (B, k) int32 — sentinel past the
+    live entries, scores (B, k))."""
+    if k > ids.shape[1]:
+        raise ValueError(f"k={k} exceeds the {ids.shape[1]} entries to rank")
+    ids_s, c_s = jax.lax.sort((ids, contrib), dimension=1, num_keys=1,
+                              is_stable=True)
+    total, _ = _run_sums(ids_s, c_s, sentinel)
+    last = jnp.concatenate([ids_s[:, 1:] != ids_s[:, :-1],
+                            jnp.ones_like(ids_s[:, :1], bool)], axis=1)
+    keep = last & (ids_s < sentinel)     # one entry per live doc: its run's end
+    neg, top_ids = jax.lax.sort(
+        (jnp.where(keep, -total, jnp.inf), jnp.where(keep, ids_s, sentinel)),
+        dimension=1, num_keys=2)
+    return top_ids[:, :k].astype(jnp.int32), -neg[:, :k]
+
+
+@jax.named_scope("fuse_topk")
+def fuse_topk(sparse_ids, sparse_scores, dense_ids, dense_scores, dense_mask,
+              n_docs, alpha, k, *, sparse_mask=None, method="interp",
+              rrf_k=60.0):
+    """Union-merge + fuse + global top-k over a corpus of n_docs.
+
+    sparse_ids/scores: (B, Ks) with optional sparse_mask for padding;
+    dense_ids/scores: (B, Kd) with dense_mask for padding. Returns
+    (ids (B, k), fused scores (B, k)), as `fuse_topk_scatter` does, by
+    sort-merge over the candidates (module docstring). k <= n_docs.
+    """
+    if k > n_docs:
+        raise ValueError(f"k={k} exceeds the corpus of {n_docs} documents")
+    sparse_mask, s_c, d_c = _contribs(sparse_ids, sparse_scores,
+                                      dense_scores, dense_mask, alpha,
+                                      method, rrf_k, sparse_mask)
+    return _merge_topk(*_entries(dense_ids, dense_mask, d_c, sparse_ids,
+                                 sparse_mask, s_c, n_docs, k), k, n_docs)
+
+
+def fuse_topk_scatter(sparse_ids, sparse_scores, dense_ids, dense_scores,
+                      dense_mask, n_docs, alpha, k, *, sparse_mask=None,
+                      method="interp", rrf_k=60.0):
+    """`fuse_topk` through an (n_docs + 1) scatter-add buffer per query:
+    the oracle the sort-merge is tested against."""
+    sparse_mask, s_c, d_c = _contribs(sparse_ids, sparse_scores,
+                                      dense_scores, dense_mask, alpha,
+                                      method, rrf_k, sparse_mask)
 
     def one(sid, sc, sm, did, dc, dm):
         fused = jnp.zeros((n_docs + 1,), jnp.float32)
@@ -103,44 +210,18 @@ def fuse_topk(sparse_ids, sparse_scores, dense_ids, dense_scores, dense_mask,
                          dense_ids, d_c, dense_mask)
 
 
+@jax.named_scope("fuse_topk")
 def fuse_topk_merge(sparse_ids, sparse_scores, dense_ids, dense_scores,
                     dense_mask, alpha, k, sentinel, *, sparse_mask=None,
                     method="interp", rrf_k=60.0):
-    """Sort-merge fusion WITHOUT an O(n_docs) scatter buffer — the serving
-    path for corpus-scale retrieval.
-
-    Duplicate ids are folded by a segment-sum over the id-sorted entry
-    list, so a doc may appear any number of times across the two sides
-    (multi-shard gathers and graph-expanded candidate lists legitimately
-    surface a doc 3+ times; the old pairwise `roll` merge silently
-    dropped the third occurrence).
+    """Sort-merge fusion over the candidates alone, for callers that know
+    no corpus size (the distributed path): no fill entries, so fewer
+    than k live ids leave sentinel ids with score -inf at the tail.
 
     sentinel: id strictly greater than any real doc id (pads sort last).
     """
-    if sparse_mask is None:
-        sparse_mask = jnp.ones_like(sparse_ids, bool)
-    s_c = side_contrib(sparse_scores, sparse_mask, alpha, method, rrf_k)
-    d_c = side_contrib(dense_scores, dense_mask, 1.0 - alpha, method, rrf_k)
-
-    def one(sid, sc, sm, did, dc, dm):
-        ids = jnp.concatenate([jnp.where(sm, sid, sentinel),
-                               jnp.where(dm, did, sentinel)])
-        contrib = jnp.concatenate([sc, dc])       # masked entries already 0
-        order = jnp.argsort(ids)
-        ids_s = jnp.take(ids, order)
-        c_s = jnp.take(contrib, order)
-        L = ids_s.shape[0]
-        # contiguous segment index per distinct id run
-        first = jnp.concatenate([jnp.ones((1,), bool),
-                                 ids_s[1:] != ids_s[:-1]])
-        seg = jnp.cumsum(first) - 1                              # (L,)
-        totals = jax.ops.segment_sum(c_s, seg, num_segments=L)
-        seg_ids = jax.ops.segment_max(ids_s, seg, num_segments=L)
-        live = (jnp.arange(L) < seg[-1] + 1) & (seg_ids < sentinel)
-        seg_ids = jnp.where(live, seg_ids, sentinel)
-        final = jnp.where(live, totals, -jnp.inf)
-        top_s, top_i = jax.lax.top_k(final, k)
-        return jnp.take(seg_ids, top_i).astype(jnp.int32), top_s
-
-    return jax.vmap(one)(sparse_ids, s_c, sparse_mask,
-                         dense_ids, d_c, dense_mask)
+    sparse_mask, s_c, d_c = _contribs(sparse_ids, sparse_scores,
+                                      dense_scores, dense_mask, alpha,
+                                      method, rrf_k, sparse_mask)
+    return _merge_topk(*_entries(dense_ids, dense_mask, d_c, sparse_ids,
+                                 sparse_mask, s_c, sentinel, 0), k, sentinel)

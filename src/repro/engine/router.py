@@ -28,14 +28,14 @@ Merge tie rule: per-host partial results merge under (score desc, doc id
 asc) — exactly `train/labels.py`'s streaming `_merge_topk` lexsort rule,
 which is also `lax.top_k`'s tie rule over an id-indexed array. Entry
 MULTIPLICITY is preserved (no id-dedup): the single-host fused tail
-scatter-adds duplicate selected slots, so the router must too; double
+sums duplicate selected slots, so the router must too; double
 counting across hosts cannot happen because shard slot-sets partition the
 selection and each shard group is accepted from exactly one replica.
 
 Exactness: hosts run the same elementwise score ops as the single-host
 fused tail (ADC LUT scoring / block dot), the merged dense candidate
 list is the same multiset as the single-host (B, S*cap) slot list, and
-fusion runs the same `fuse_topk` scatter — so `method="interp"` (the
+fusion runs the same `fuse_topk` sort-merge — so `method="interp"` (the
 paper default) is BITWISE identical to the single-host engine. RRF
 breaks exact-score ties by list position, so rrf parity is exact except
 on exact dense-score ties across distinct docs.
@@ -98,7 +98,7 @@ def merge_partial_topk(parts, k):
     parts: list of (ids (B, Ki) int, scores (B, Ki) float) — Ki may vary
     per part. Entries with non-finite scores or sentinel ids are treated
     as padding. Duplicate ids are KEPT at their multiplicity (the fused
-    tail scatter-adds duplicate slots; at-most-once delivery per shard
+    tail sums duplicate slots; at-most-once delivery per shard
     group is the router's job, not the merge's).
 
     Returns (ids (B, k) int64, scores (B, k) float32); when fewer than k
@@ -589,7 +589,7 @@ class ShardRouter:
 
     def _fuse_fn(self, bucket, kd):
         """Fuse the merged dense candidate list with the sparse side — the
-        same fuse_topk scatter the single-host fused tail ends in."""
+        same fuse_topk the single-host fused tail ends in."""
         def build():
             cfg, n_docs, k = self.cfg, self.index.n_docs, self.k
 

@@ -2,6 +2,7 @@
 LSTM training improves selection, end-to-end quality, fusion exactness."""
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -105,92 +106,264 @@ def test_sparse_retrieval_exact_when_untruncated():
 # fusion
 # ---------------------------------------------------------------------------
 
+def _draw_ids(rng, D, shape, distinct):
+    if distinct:
+        return np.stack([rng.choice(D, shape[1], replace=False)
+                         for _ in range(shape[0])]).astype(np.int32)
+    return rng.integers(0, D, shape).astype(np.int32)
+
+
+def _fusion_case(name, rng):
+    """One fusion case: (n_docs, k, alpha, variants, multiplicity <= 2);
+    each variant is (sid, ss, sm, did, ds, dm), all variants of a case
+    meant to fuse alike (the padding case's two junk tails)."""
+    B = 2
+    if name == "distinct":
+        D, Ks, Kd, k = 500, 40, 60, 20
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.ones((B, Ks), bool)
+        did = _draw_ids(rng, D, (B, Kd), True)
+        dm = rng.random((B, Kd)) > 0.2
+        ds = np.where(dm, rng.random((B, Kd)), 0.0).astype(np.float32)
+        return D, k, 0.5, [(sid, ss, sm, did, ds, dm)], True
+    if name == "any_multiplicity":
+        # a doc may repeat within a side and across sides at any
+        # multiplicity, behind a ragged valid prefix on the sparse side;
+        # alpha != 0.5 so no cross-side rank ties under rrf
+        B, D, Ks, Kd, k = 3, 120, 30, 40, 15
+        sid = _draw_ids(rng, D, (B, Ks), False)
+        ss = np.sort(rng.random((B, Ks)))[:, ::-1].astype(np.float32)
+        sm = np.arange(Ks)[None, :] < rng.integers(0, Ks + 1, (B, 1))
+        did = _draw_ids(rng, D, (B, Kd), False)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.2
+        return D, k, 0.43, [(sid, ss, sm, did, ds, dm)], False
+    if name == "sparse_padding":
+        # entries behind the sparse valid mask must not shift the
+        # normalization, the ranks or the top-k: two junk tails
+        D, Ks, Kd, k = 200, 24, 24, 10
+        n_valid = int(rng.integers(1, Ks))
+        sid_v = rng.choice(D, n_valid, replace=False)
+        ss_v = np.sort(rng.random(n_valid))[::-1]
+        sm = np.broadcast_to(np.arange(Ks) < n_valid, (1, Ks))
+        did = _draw_ids(rng, D, (1, Kd), True)
+        ds = rng.random((1, Kd)).astype(np.float32)
+        dm = np.ones((1, Kd), bool)
+        variants = []
+        for junk_ids, junk_scores in (
+                (rng.integers(0, D, Ks - n_valid),
+                 rng.random(Ks - n_valid) * 10 - 5),
+                (np.zeros(Ks - n_valid), np.full(Ks - n_valid, 99.0))):
+            sid = np.concatenate([sid_v, junk_ids])[None].astype(np.int32)
+            ss = np.concatenate([ss_v, junk_scores])[None].astype(np.float32)
+            variants.append((sid, ss, sm, did, ds, dm))
+        return D, k, 0.5, variants, True
+    if name == "few_positive":
+        # fewer live candidates than k, some with ids < k: the fill
+        # entries must give the buffer's zero-score tail, lowest ids first
+        D, Ks, Kd, k = 4096, 8, 32, 50
+        sid = _draw_ids(rng, 2 * k, (B, Ks), True)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.arange(Ks)[None, :] < rng.integers(1, 4, (B, 1))
+        did = _draw_ids(rng, 2 * k, (B, Kd), True)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.85
+        return D, k, 0.43, [(sid, ss, sm, did, ds, dm)], True
+    if name == "dense_all_masked":
+        D, Ks, Kd, k = 1000, 30, 64, 40
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.ones((B, Ks), bool)
+        did = _draw_ids(rng, D, (B, Kd), False)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = np.zeros((B, Kd), bool)
+        return D, k, 0.5, [(sid, ss, sm, did, ds, dm)], True
+    if name == "ragged_sparse":
+        D, Ks, Kd, k = 1000, 50, 64, 30
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = np.sort(rng.random((B, Ks)))[:, ::-1].astype(np.float32)
+        sm = np.arange(Ks)[None, :] < rng.integers(0, Ks + 1, (B, 1))
+        did = _draw_ids(rng, D, (B, Kd), True)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.3
+        return D, k, 0.43, [(sid, ss, sm, did, ds, dm)], True
+    if name == "multiplicity_3plus":
+        # ids from a pool of 24 in a corpus of 2,048: every doc is
+        # several times on each side
+        D, Ks, Kd, k = 2048, 40, 80, 30
+        sid = _draw_ids(rng, 24, (B, Ks), False)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.ones((B, Ks), bool)
+        did = _draw_ids(rng, 24, (B, Kd), False)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.1
+        return D, k, 0.43, [(sid, ss, sm, did, ds, dm)], False
+    if name == "small_corpus":
+        # a candidate list longer than the corpus: the fill entries
+        # repeat candidate ids, which must not count twice
+        D, Ks, Kd, k = 100, 30, 60, 20
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.ones((B, Ks), bool)
+        did = _draw_ids(rng, D, (B, Kd), True)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.2
+        return D, k, 0.5, [(sid, ss, sm, did, ds, dm)], True
+    if name == "k_equals_n_docs":
+        D, Ks, Kd, k = 64, 20, 40, 64
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = rng.random((B, Ks)).astype(np.float32)
+        sm = np.arange(Ks)[None, :] < rng.integers(0, Ks + 1, (B, 1))
+        did = _draw_ids(rng, D, (B, Kd), True)
+        ds = rng.random((B, Kd)).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.3
+        return D, k, 0.43, [(sid, ss, sm, did, ds, dm)], True
+    if name == "tied_scores":
+        # scores on a grid of 4 (interp) and single-side docs at equal
+        # ranks (rrf) give groups of equal fused scores across the k-th
+        # place: they must rank lowest id first, as over the buffer
+        D, Ks, Kd, k = 300, 40, 60, 50
+        sid = _draw_ids(rng, D, (B, Ks), True)
+        ss = (rng.integers(0, 4, (B, Ks)) / 4).astype(np.float32)
+        sm = np.ones((B, Ks), bool)
+        did = _draw_ids(rng, D, (B, Kd), True)
+        ds = (rng.integers(0, 4, (B, Kd)) / 4).astype(np.float32)
+        dm = rng.random((B, Kd)) > 0.2
+        return D, k, 0.5, [(sid, ss, sm, did, ds, dm)], True
+    raise KeyError(name)
+
+
+_SCATTER = jax.jit(fusion_lib.fuse_topk_scatter, static_argnums=(5, 6, 7),
+                   static_argnames="method")
+_FUSE = jax.jit(fusion_lib.fuse_topk, static_argnums=(5, 6, 7),
+                static_argnames="method")
+_MERGE = jax.jit(fusion_lib.fuse_topk_merge, static_argnums=(5, 6, 7),
+                 static_argnames="method")
+FUSION_CASES = ("distinct", "any_multiplicity", "sparse_padding",
+                "few_positive", "dense_all_masked", "ragged_sparse",
+                "multiplicity_3plus", "small_corpus", "k_equals_n_docs",
+                "tied_scores")
+
+
+@pytest.mark.parametrize("case", FUSION_CASES)
 @settings(max_examples=20, deadline=None)
 @given(st.integers(0, 2 ** 31 - 1))
-def test_fusion_merge_equals_scatter(seed):
+def test_fusion_merge_equals_scatter(case, seed):
+    """`fuse_topk` and `fuse_topk_merge` (both sort-merge) against the
+    scatter buffer as the oracle, for both methods: ids equal; scores
+    bitwise up to two entries a doc, within 1e-6 beyond (XLA leaves the
+    order of a scatter's duplicate updates open). `fuse_topk_merge` has
+    no fill entries, so it answers for the oracle's positive scores."""
     rng = np.random.default_rng(seed)
-    D, Ks, Kd, k = 500, 40, 60, 20
-    sid = jnp.asarray(rng.choice(D, (2, Ks), replace=False), jnp.int32)
-    ss = jnp.asarray(rng.random((2, Ks)), jnp.float32)
-    did = jnp.asarray(rng.choice(D, (2, Kd), replace=False), jnp.int32)
-    ds = jnp.asarray(rng.random((2, Kd)), jnp.float32)
-    dm = jnp.asarray(rng.random((2, Kd)) > 0.2)
-    a = 0.5
-    i1, s1 = fusion_lib.fuse_topk(sid, ss, did, jnp.where(dm, ds, 0.0), dm,
-                                  D, a, k)
-    i2, s2 = fusion_lib.fuse_topk_merge(sid, ss, did, jnp.where(dm, ds, 0.0),
-                                        dm, a, k, sentinel=D + 7)
-    np.testing.assert_allclose(np.asarray(s1), np.asarray(s2), rtol=1e-5,
-                               atol=1e-6)
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.integers(0, 2 ** 31 - 1))
-def test_fusion_merge_equals_scatter_any_multiplicity(seed):
-    """Merge-path == scatter-oracle with ids drawn WITH replacement (a doc
-    may repeat within a side and across sides at any multiplicity) and a
-    ragged valid prefix on the sparse side — for both fusion methods."""
-    rng = np.random.default_rng(seed)
-    D, Ks, Kd, k = 120, 30, 40, 15
-    sid = jnp.asarray(rng.integers(0, D, (3, Ks)), jnp.int32)
-    ss = jnp.asarray(np.sort(rng.random((3, Ks)))[:, ::-1].copy(),
-                     jnp.float32)
-    sm = jnp.arange(Ks)[None, :] < jnp.asarray(
-        rng.integers(0, Ks + 1, (3, 1)))             # ragged prefix
-    did = jnp.asarray(rng.integers(0, D, (3, Kd)), jnp.int32)
-    ds = jnp.asarray(rng.random((3, Kd)), jnp.float32)
-    dm = jnp.asarray(rng.random((3, Kd)) > 0.2)
-    a = 0.43                                         # != 0.5: no cross-side
-    for method in fusion_lib.FUSION_METHODS:         # rank ties under rrf
-        i1, s1 = fusion_lib.fuse_topk(
-            sid, ss, did, ds, dm, D, a, k, sparse_mask=sm, method=method)
-        i2, s2 = fusion_lib.fuse_topk_merge(
-            sid, ss, did, ds, dm, a, k, sentinel=D + 7, sparse_mask=sm,
-            method=method)
-        np.testing.assert_allclose(np.asarray(s1), np.asarray(s2),
-                                   rtol=1e-5, atol=1e-6, err_msg=method)
-        np.testing.assert_array_equal(np.asarray(i1), np.asarray(i2),
-                                      err_msg=method)
-
-
-@settings(max_examples=20, deadline=None)
-@given(st.integers(0, 2 ** 31 - 1))
-def test_fusion_ignores_sparse_padding(seed):
-    """Regression for the padding bug: entries behind the sparse valid
-    mask must not shift normalization, ranks, or the fused top-k — two
-    different junk tails under the same mask fuse bitwise identically."""
-    rng = np.random.default_rng(seed)
-    D, Ks, Kd, k = 200, 24, 24, 10
-    n_valid = int(rng.integers(1, Ks))
-    sid_v = rng.choice(D, n_valid, replace=False).astype(np.int32)
-    ss_v = np.sort(rng.random(n_valid).astype(np.float32))[::-1].copy()
-    sm = jnp.asarray((np.arange(Ks) < n_valid)[None, :])
-    did = jnp.asarray(rng.choice(D, (1, Kd), replace=False), jnp.int32)
-    ds = jnp.asarray(rng.random((1, Kd)), jnp.float32)
-    dm = jnp.ones((1, Kd), bool)
-
-    def pad(junk_ids, junk_scores):
-        sid = np.concatenate([sid_v, junk_ids]).astype(np.int32)
-        ss = np.concatenate([ss_v, junk_scores]).astype(np.float32)
-        return jnp.asarray(sid[None, :]), jnp.asarray(ss[None, :])
-
-    pads = [pad(rng.integers(0, D, Ks - n_valid),
-                rng.random(Ks - n_valid) * 10 - 5),
-            pad(np.zeros(Ks - n_valid, np.int64),
-                np.full(Ks - n_valid, 99.0))]
+    D, k, a, variants, pairs_only = _fusion_case(case, rng)
+    Ks, Kd = variants[0][0].shape[1], variants[0][3].shape[1]
     for method in fusion_lib.FUSION_METHODS:
         outs = []
-        for sid, ss in pads:
-            i1, s1 = fusion_lib.fuse_topk(sid, ss, did, ds, dm, D, 0.5, k,
-                                          sparse_mask=sm, method=method)
-            i2, s2 = fusion_lib.fuse_topk_merge(sid, ss, did, ds, dm, 0.5,
-                                                k, sentinel=D + 7,
-                                                sparse_mask=sm,
-                                                method=method)
-            outs.append((np.asarray(i1), np.asarray(s1),
-                         np.asarray(i2), np.asarray(s2)))
-        for got, want in zip(outs[0], outs[1]):
-            np.testing.assert_array_equal(got, want, err_msg=method)
+        for sid, ss, sm, did, ds, dm in variants:
+            args = (jnp.asarray(sid), jnp.asarray(ss), jnp.asarray(did),
+                    jnp.asarray(ds), jnp.asarray(dm))
+            kw = dict(sparse_mask=jnp.asarray(sm), method=method)
+            oi, os_ = (np.asarray(x) for x in _SCATTER(*args, D, a, k, **kw))
+            fi, fs = (np.asarray(x) for x in _FUSE(*args, D, a, k, **kw))
+            km = min(k, Ks + Kd)        # the merge ranks live entries only
+            mi, ms = (np.asarray(x) for x in _MERGE(*args, a, km, D + 7,
+                                                    **kw))
+            pos = os_[:, :km] > 0
+            if pairs_only:
+                same = np.testing.assert_array_equal
+            else:
+                same = functools.partial(np.testing.assert_allclose, rtol=0,
+                                         atol=1e-6)
+            np.testing.assert_array_equal(fi, oi, err_msg=method)
+            same(fs, os_, err_msg=method)
+            np.testing.assert_array_equal(mi[pos], oi[:, :km][pos],
+                                          err_msg=method)
+            same(ms[pos], os_[:, :km][pos], err_msg=method)
+            outs.append((fi, fs, mi, ms))
+        for got in outs[1:]:            # junk tails fuse bitwise alike
+            for g, w in zip(got, outs[0]):
+                np.testing.assert_array_equal(g, w, err_msg=method)
+
+
+def test_fuse_topk_rejects_k_above_corpus():
+    """k fill entries stand for the corpus's zero-score tail, so k may
+    not exceed n_docs, as the buffer's top-k over n_docs cannot; nor may
+    it exceed the entries of `fuse_topk_merge`, which has no fill."""
+    sid = jnp.zeros((1, 4), jnp.int32)
+    did = jnp.zeros((1, 8), jnp.int32)
+    with pytest.raises(ValueError, match="exceeds the corpus"):
+        fusion_lib.fuse_topk(sid, jnp.ones((1, 4)), did, jnp.ones((1, 8)),
+                             jnp.ones((1, 8), bool), 10, 0.5, 11)
+    # without fill entries the merge ranks at most its 12 entries
+    with pytest.raises(ValueError, match="entries to rank"):
+        fusion_lib.fuse_topk_merge(sid, jnp.ones((1, 4)), did,
+                                   jnp.ones((1, 8)), jnp.ones((1, 8), bool),
+                                   0.5, 13, 10)
+
+
+def _jaxpr_shapes(jaxpr):
+    """(primitive names, every array shape) of a jaxpr and its sub-jaxprs."""
+    prims, shapes = set(), set()
+    for eqn in jaxpr.eqns:
+        prims.add(eqn.primitive.name)
+        for v in list(eqn.invars) + list(eqn.outvars):
+            shapes.add(tuple(getattr(v.aval, "shape", ())))
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (tuple, list)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    sp, ss = _jaxpr_shapes(inner)
+                    prims |= sp
+                    shapes |= ss
+    return prims, shapes
+
+
+def test_run_sums_pass_count_ignores_dead_entries():
+    """The run-sum loop makes (longest live run - 1) passes: the masked
+    entries, which share the sentinel id and are most of the dense slots
+    at serving, form no run however many there are."""
+    sentinel = 1000
+    ids = np.array([[3, 3, 7, 9, 9, 9] + [sentinel] * 40,
+                    [1, 2, 4, 4, 5, 6] + [sentinel] * 40], np.int32)
+    c = np.arange(ids.size, dtype=np.float32).reshape(ids.shape) + 1.0
+    total, passes = jax.jit(fusion_lib._run_sums, static_argnums=2)(
+        jnp.asarray(ids), jnp.asarray(c), sentinel)
+    assert int(passes) == 2
+    total = np.asarray(total)
+    np.testing.assert_array_equal(total[0, :6], [1, 3, 3, 4, 9, 15])
+    np.testing.assert_array_equal(total[1, :6], [47, 48, 49, 99, 51, 52])
+    np.testing.assert_array_equal(total[:, 6:], c[:, 6:])
+
+
+def test_fuse_topk_serving_shapes_have_no_corpus_buffer():
+    """At the serving shapes (16 queries, 1,000 sparse + 32 x 2,048 dense
+    entries, 2^21 docs, top 1,000) fuse_topk traces no scatter and no
+    array with an n_docs or n_docs + 1 dimension. Abstract inputs only."""
+    B, Ks, Kd, D, k = 16, 1000, 65536, 2 ** 21, 1000
+    spec = jax.ShapeDtypeStruct
+    for method in fusion_lib.FUSION_METHODS:
+        jaxpr = jax.make_jaxpr(
+            lambda sid, ss, did, ds, dm: fusion_lib.fuse_topk(
+                sid, ss, did, ds, dm, D, 0.5, k, method=method))(
+            spec((B, Ks), jnp.int32), spec((B, Ks), jnp.float32),
+            spec((B, Kd), jnp.int32), spec((B, Kd), jnp.float32),
+            spec((B, Kd), jnp.bool_))
+        prims, shapes = _jaxpr_shapes(jaxpr.jaxpr)
+        assert not any("scatter" in p for p in prims), (method, prims)
+        assert not any(d in (D, D + 1) for s in shapes for d in s), method
+        # ties rank by the sort's id key, not by a top-k's order
+        assert "sort" in prims and "top_k" not in prims, prims
+    # the oracle at the same shapes does build the buffer: the walk sees it
+    jaxpr = jax.make_jaxpr(
+        lambda sid, ss, did, ds, dm: fusion_lib.fuse_topk_scatter(
+            sid, ss, did, ds, dm, D, 0.5, k))(
+        spec((B, Ks), jnp.int32), spec((B, Ks), jnp.float32),
+        spec((B, Kd), jnp.int32), spec((B, Kd), jnp.float32),
+        spec((B, Kd), jnp.bool_))
+    prims, shapes = _jaxpr_shapes(jaxpr.jaxpr)
+    assert any("scatter" in p for p in prims)
+    assert any(D + 1 in s for s in shapes)
 
 
 def test_rrf_matches_rank_oracle():
